@@ -25,7 +25,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import time
@@ -176,12 +175,10 @@ def main() -> int:
         "no degraded records in the frozen querylog window"
 
     # -- and the CLI agrees --------------------------------------------
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.obs.flight", bundle, "--top", "8"],
-        capture_output=True, text=True)
-    print(proc.stdout)
-    assert proc.returncode == 0, (
-        f"replay CLI failed ({proc.returncode}):\n{proc.stderr}")
+    # (in-process: this process holds the accelerator, a child could
+    # not initialise JAX on it)
+    rc = obs_flight.main([bundle, "--top", "8"])
+    assert rc == 0, f"replay CLI failed ({rc})"
 
     n_ex = sum(len(v) for b in manifest["exemplars"].values()
                for v in b.values())

@@ -33,6 +33,9 @@ def main() -> None:
     ap.add_argument("--queries", type=int, default=None)
     ap.add_argument("--skip-fig3", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     scale = args.scale or (0.5 if args.full else 0.25)
     n_q = args.queries or (1000 if args.full else 400)
 

@@ -95,6 +95,64 @@ def _devices_for(n_shards: int, n_avail: int) -> int:
     return 1
 
 
+def fused_program(mesh, *, shards_per_dev: int, nt: int, dim: int,
+                  kcap: int, impl: str, interpret: bool):
+    """The single collective serving program at one static candidate
+    capacity: replicated route + rect quantization, per-local-shard
+    fused prune+compact+scan, and the cross-shard ``psum`` OR-reduce and
+    ``pmax`` capacity check — all in ONE ``shard_map``-ed jit,
+    collapsing the old two-dispatch (+ host bucket sync) round.
+
+    Every array is an argument — ``(side, grid, qfine, qcoarse,
+    entries, tree_shard, tree_qs, tree_qe, us, rsoa)``, the arena stacks
+    sharded over the mesh's ``data`` axis and the rest replicated — so
+    the program can be lowered from shapes alone (the TPU compile
+    rehearsal does, on a described mesh)."""
+    L = shards_per_dev
+
+    def fused(side, grid, qfine, qcoarse, entries, tshard, tqs, tqe, us,
+              rsoa):
+        # qfine/qcoarse/entries: (L, ...) local shard stacks;
+        # us/rsoa replicated.  Everything below the routing runs
+        # against local shards only.
+        tid, valid, forced = side.route(us, rsoa)
+        t = jnp.maximum(tid, 0)
+        own = jnp.where(valid, tshard[t], -1)
+        r16, r32 = quantize_rects(grid, rsoa, dim)
+        first = jax.lax.axis_index(_AXIS) * L
+        dummy_ids = jnp.zeros((1, entries.shape[-1]), jnp.int32)
+        hit = jnp.zeros((rsoa.shape[1],), jnp.int32)
+        cnts = []
+        for l in range(L):
+            mine = own == first + l
+            qs = jnp.where(mine, tqs[t], 0)
+            qe = jnp.where(mine, tqe[t], 0)
+            if impl == "pallas":
+                out, cnt = fused_serve_pallas(
+                    qfine[l], qcoarse[l], entries[l], dummy_ids,
+                    r16, r32, rsoa, qs, qe, mode="reach", kcap=kcap,
+                    nt=nt, dim=dim, interpret=interpret)
+            else:
+                out, cnt = fused_serve_xla(
+                    qfine[l], qcoarse[l], entries[l], dummy_ids,
+                    r16, r32, rsoa, qs, qe, mode="reach", kcap=kcap,
+                    nt=nt, dim=dim)
+            hit = hit | out
+            cnts.append(cnt)
+        cnt = jnp.stack(cnts)
+        mx = jax.lax.pmax(cnt.max(), _AXIS)
+        # OR-reduce across shards: hits are 0/1 and each query's
+        # tree lives on exactly one shard, so a sum is an OR
+        return forced, own, jax.lax.psum(hit, _AXIS), cnt, mx
+
+    return jax.jit(shard_map(
+        fused, mesh,
+        in_specs=(P(), P(), P(_AXIS), P(_AXIS), P(_AXIS), P(), P(), P(),
+                  P(), P()),
+        out_specs=(P(), P(), P(), P(_AXIS), P()),
+    ))
+
+
 class ShardedEngine:
     """Compile-once sharded engine over a built ``TwoDReachIndex``.
 
@@ -153,24 +211,24 @@ class ShardedEngine:
         # rounding to be outward; sharing the grid keeps the replicated
         # rect quantization identical on every device)
         ent = index.forest.entries
-        self._grid = make_quant_grid(
+        replicated = NamedSharding(mesh, P())
+        self._grid = jax.device_put(make_quant_grid(
             np.concatenate([ent[:, : self.dim].min(0),
                             ent[:, self.dim:].max(0)]).astype(np.float64)
             if len(ent) else None,
-            self.dim)
+            self.dim), replicated)
         self._qfine = put(
             jax.vmap(lambda p: quantize_fine(self._grid, p, self.dim))(
                 jnp.asarray(fine)), specs["fine"])
         self._qcoarse = put(
             jax.vmap(lambda p: quantize_coarse(self._grid, p, self.dim))(
                 jnp.asarray(coarse)), specs["coarse"])
-        self._tree_shard = put(
-            jnp.asarray(self.partition.tree_shard), specs["tree_shard"])
-        self._tree_qs = put(
-            jnp.asarray(self.partition.tree_qs), specs["tree_qs"])
-        self._tree_qe = put(
-            jnp.asarray(self.partition.tree_qe), specs["tree_qe"])
-        self._side = PointerSide(index)
+        # replicated routing: tree -> (owning shard, local arena slice),
+        # passed to the shard_map programs as arguments
+        self._routing = tuple(
+            put(np.asarray(getattr(self.partition, k)), specs[k])
+            for k in ("tree_shard", "tree_qs", "tree_qe"))
+        self._side = PointerSide(index, sharding=replicated)
 
         self.stats: Dict[str, float] = {
             "uploads": 1, "batches": 0, "queries": 0,
@@ -198,7 +256,7 @@ class ShardedEngine:
         self._kb_hwm = 1
         self._fused_impl = ("pallas" if jax.default_backend() == "tpu"
                             else "xla")
-        self._padder = DevicePadder(self.dim)
+        self._padder = DevicePadder(self.dim, sharding=replicated)
         # fused collective programs, memoised per static capacity —
         # shard_map cannot take static kwargs, so each ratcheted kcap
         # gets its own program object (bounded: the hwm is monotone
@@ -212,12 +270,11 @@ class ShardedEngine:
     # ------------------------------------------------------------------
 
     def _make_prepare(self):
-        side, dim = self._side, self.dim
+        dim = self.dim
         interpret = self._interpret
         L, nt = self._shards_per_dev, self.n_tiles
-        tshard, tqs, tqe = self._tree_shard, self._tree_qs, self._tree_qe
 
-        def prepare(fine, coarse, us, rsoa):
+        def prepare(side, fine, coarse, tshard, tqs, tqe, us, rsoa):
             # fine/coarse: (L, 2*dim, ·) local shard stack; us/rsoa
             # replicated.  Routing is replicated compute (identical on
             # every device); only the prune runs against local pyramids.
@@ -246,7 +303,7 @@ class ShardedEngine:
 
         return shard_map(
             prepare, self.mesh,
-            in_specs=(P(_AXIS), P(_AXIS), P(), P()),
+            in_specs=(P(), P(_AXIS), P(_AXIS), P(), P(), P(), P(), P()),
             out_specs=(P(), P(), P(_AXIS), P(_AXIS), P(_AXIS),
                        P(_AXIS), P()),
         )
@@ -274,61 +331,14 @@ class ShardedEngine:
         )
 
     def _fused_prog(self, kcap: int):
-        """The single collective serving program at one static candidate
-        capacity: replicated route + rect quantization, per-local-shard
-        fused prune+compact+scan, and the cross-shard ``psum`` OR-reduce
-        and ``pmax`` capacity check — all in ONE ``shard_map``-ed jit,
-        collapsing the old two-dispatch (+ host bucket sync) round."""
+        """The collective serving program at one static capacity,
+        memoised (see :func:`fused_program`)."""
         prog = self._fused_progs.get(kcap)
-        if prog is not None:
-            return prog
-        side, dim = self._side, self.dim
-        interpret = self._interpret
-        impl = self._fused_impl
-        L, nt = self._shards_per_dev, self.n_tiles
-        tshard, tqs, tqe = self._tree_shard, self._tree_qs, self._tree_qe
-        grid = self._grid
-
-        def fused(qfine, qcoarse, entries, us, rsoa):
-            # qfine/qcoarse/entries: (L, ...) local shard stacks;
-            # us/rsoa replicated.  Everything below the routing runs
-            # against local shards only.
-            tid, valid, forced = side.route(us, rsoa)
-            t = jnp.maximum(tid, 0)
-            own = jnp.where(valid, tshard[t], -1)
-            r16, r32 = quantize_rects(grid, rsoa, dim)
-            first = jax.lax.axis_index(_AXIS) * L
-            dummy_ids = jnp.zeros((1, entries.shape[-1]), jnp.int32)
-            hit = jnp.zeros((rsoa.shape[1],), jnp.int32)
-            cnts = []
-            for l in range(L):
-                mine = own == first + l
-                qs = jnp.where(mine, tqs[t], 0)
-                qe = jnp.where(mine, tqe[t], 0)
-                if impl == "pallas":
-                    out, cnt = fused_serve_pallas(
-                        qfine[l], qcoarse[l], entries[l], dummy_ids,
-                        r16, r32, rsoa, qs, qe, mode="reach", kcap=kcap,
-                        nt=nt, dim=dim, interpret=interpret)
-                else:
-                    out, cnt = fused_serve_xla(
-                        qfine[l], qcoarse[l], entries[l], dummy_ids,
-                        r16, r32, rsoa, qs, qe, mode="reach", kcap=kcap,
-                        nt=nt, dim=dim)
-                hit = hit | out
-                cnts.append(cnt)
-            cnt = jnp.stack(cnts)
-            mx = jax.lax.pmax(cnt.max(), _AXIS)
-            # OR-reduce across shards: hits are 0/1 and each query's
-            # tree lives on exactly one shard, so a sum is an OR
-            return forced, own, jax.lax.psum(hit, _AXIS), cnt, mx
-
-        prog = jax.jit(shard_map(
-            fused, self.mesh,
-            in_specs=(P(_AXIS), P(_AXIS), P(_AXIS), P(), P()),
-            out_specs=(P(), P(), P(), P(_AXIS), P()),
-        ))
-        self._fused_progs[kcap] = prog
+        if prog is None:
+            prog = self._fused_progs[kcap] = fused_program(
+                self.mesh, shards_per_dev=self._shards_per_dev,
+                nt=self.n_tiles, dim=self.dim, kcap=kcap,
+                impl=self._fused_impl, interpret=self._interpret)
         return prog
 
     # ------------------------------------------------------------------
@@ -344,6 +354,14 @@ class ShardedEngine:
             + self._padder._cache_size()
             + sum(p._cache_size() for p in self._fused_progs.values())
         )
+
+    def arena_devices(self) -> list:
+        """The device holding each shard's arena, by shard id."""
+        held = {}
+        for piece in self._entries.addressable_shards:
+            for s in range(*piece.index[0].indices(self.n_shards)):
+                held[s] = piece.device
+        return [held[s] for s in range(self.n_shards)]
 
     def shard_of(self, us: np.ndarray) -> np.ndarray:
         """Host-side vertex -> owning shard (-1: excluded / no tree) —
@@ -402,8 +420,8 @@ class ShardedEngine:
                 while True:
                     kcap = min(self._kb_hwm, self.n_tiles)
                     forced, own, hit, cnt, mx = self._fused_prog(kcap)(
-                        self._qfine, self._qcoarse, self._entries,
-                        us_dev, rsoa_dev)
+                        self._side, self._grid, self._qfine, self._qcoarse,
+                        self._entries, *self._routing, us_dev, rsoa_dev)
                     # int(mx) blocks on the whole collective launch
                     mxi = int(mx)
                     if mxi <= kcap or kcap >= self.n_tiles:
@@ -430,8 +448,8 @@ class ShardedEngine:
 
             with span("cluster.route_prune", cat="cluster"):
                 forced, own, qs, qe, cand, cnt, mx = self._prepare(
-                    self._fine, self._coarse, us_dev, rsoa_dev
-                )
+                    self._side, self._fine, self._coarse, *self._routing,
+                    us_dev, rsoa_dev)
                 # int(mx) blocks on the sharded prune + pmax round
                 self._kb_hwm = max(
                     self._kb_hwm,

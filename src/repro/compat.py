@@ -1,34 +1,16 @@
-"""Version-compat shims for the jax surface this repo spans.
+"""``jax.shard_map`` with the replication check off by default.
 
-jax moved ``shard_map`` from ``jax.experimental`` to the top level, and
-separately renamed the replication-check kwarg (``check_rep`` ->
-``check_vma``) — on independent release schedules, so neither location
-nor version number predicts the kwarg.  Detect both from what the
-installed jax actually exposes.
+The serving and model programs return values they reduce themselves
+(``psum``/``pmax``), so the static varying-manual-axes check
+(``check_vma``) is opt-in.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.  ``check=False`` (default)
-    disables the static replication check under whichever kwarg the
-    installed jax spells it."""
-    kw = {} if check else dict(_NOCHECK)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+    """``jax.shard_map`` with ``check_vma=check`` (default off)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

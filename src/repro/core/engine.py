@@ -59,6 +59,7 @@ cluster engine (:mod:`repro.cluster`) serves the same structures:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Optional, Tuple
 
@@ -135,6 +136,11 @@ def _popcount32_jnp(x: jax.Array) -> jax.Array:
 UPLOAD_COUNTERS = CounterDict(
     "engine.upload.", ("host_uploads", "device_adoptions"))
 
+_SIDE_ARRAYS = ("_coords", "_excluded", "_vertex_tree", "_vertex_comp",
+                "_bits", "_rank", "_tree_ptrs")
+
+
+@jax.tree_util.register_pytree_node_class
 class PointerSide:
     """Device-resident vertex→tree lookup side of a 2DReach index.
 
@@ -142,22 +148,43 @@ class PointerSide:
     excluded mask, and the variant's pointer structure — and evaluates
     the fused lookup / Alg. 2 routing inside whatever jit traces it.
     In the cluster engine these arrays are *replicated* per device while
-    the R-tree arenas shard.
+    the R-tree arenas shard: pass the mesh's replicated ``sharding``.
+    A pytree (arrays as leaves), so a program can take it as an
+    argument instead of closing over it.
     """
 
-    def __init__(self, index: TwoDReachIndex):
+    def __init__(self, index: TwoDReachIndex, sharding=None):
         self.variant = index.variant
         self.dim = index.forest.dim
-        self._coords = jnp.asarray(index.coords, jnp.float32)
-        self._excluded = jnp.asarray(index.excluded)
+
+        def put(x, dtype=None):
+            x = np.asarray(x, dtype)
+            return (jnp.asarray(x) if sharding is None
+                    else jax.device_put(x, sharding))
+
+        self._coords = put(index.coords, np.float32)
+        self._excluded = put(index.excluded)
+        self._vertex_tree = self._vertex_comp = None
+        self._bits = self._rank = self._tree_ptrs = None
         if self.variant == "pointer":
-            self._vertex_comp = jnp.asarray(index.vertex_comp, jnp.int32)
-            self._bits = jnp.asarray(index.bitrank.bits)
-            self._rank = jnp.asarray(index.bitrank.rank, jnp.int32)
-            self._tree_ptrs = jnp.asarray(index.tree_ptrs, jnp.int32)
-            self._vertex_tree = None
+            self._vertex_comp = put(index.vertex_comp, np.int32)
+            self._bits = put(index.bitrank.bits)
+            self._rank = put(index.bitrank.rank, np.int32)
+            self._tree_ptrs = put(index.tree_ptrs, np.int32)
         else:
-            self._vertex_tree = jnp.asarray(index.vertex_tree, jnp.int32)
+            self._vertex_tree = put(index.vertex_tree, np.int32)
+
+    def tree_flatten(self):
+        return (tuple(getattr(self, f) for f in _SIDE_ARRAYS),
+                (self.variant, self.dim))
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        side = object.__new__(cls)
+        side.variant, side.dim = aux
+        for f, x in zip(_SIDE_ARRAYS, leaves):
+            setattr(side, f, x)
+        return side
 
     def lookup(self, us: jax.Array) -> jax.Array:
         """Fused vertex -> tree id (-1: excluded / no tree), in-jit."""
@@ -197,6 +224,10 @@ class PointerSide:
         return tid, valid, exc & inr
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["entries", "fine", "coarse", "entry_off"],
+    meta_fields=["n_tiles"])
 @dataclasses.dataclass(frozen=True)
 class TileArena:
     """One uploaded SoA entry arena + its tile-MBR pyramid."""
@@ -240,6 +271,21 @@ class TileArena:
             )
         esoa, off = forest_soa(forest)        # cached transposition
         return cls.upload(esoa, off, dim)
+
+
+class _WithDeviceArrays:
+    """A jitted ``fn(dev, *args)`` called as ``fn(*args)`` with the
+    engine's device-array pytree ``dev`` as its first argument."""
+
+    def __init__(self, jitted, dev):
+        self._jitted = jitted
+        self._dev = dev
+
+    def __call__(self, *args, **kw):
+        return self._jitted(self._dev, *args, **kw)
+
+    def _cache_size(self) -> int:
+        return self._jitted._cache_size()
 
 
 def compact_candidates(mask: jax.Array, nt: int
@@ -295,11 +341,14 @@ class DevicePadder:
     batch's donation inputs (serving consumes a batch's rects strictly
     before the same bucket pads again, so the aliasing is safe), which
     lets XLA write each fill into the existing allocation.  The cache
-    size feeds the engine's ``n_compiles`` introspection.
+    size feeds the engine's ``n_compiles`` introspection.  ``sharding``
+    places the buffers (the cluster engine replicates them over its
+    mesh); ``None`` keeps them on the default device.
     """
 
-    def __init__(self, dim: int):
+    def __init__(self, dim: int, sharding=None):
         self.dim = dim
+        self._sharding = sharding
         self._bufs: Dict[int, Tuple[jax.Array, jax.Array]] = {}
         self._stage: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
 
@@ -313,10 +362,15 @@ class DevicePadder:
             r_o = jnp.where(live[None, :], r_stage, inert)
             return us_o, r_o
 
-        self._fill = jax.jit(fill, donate_argnums=(0, 1))
+        self._fill = jax.jit(fill, donate_argnums=(0, 1),
+                             out_shardings=sharding)
 
     def _cache_size(self) -> int:
         return self._fill._cache_size()
+
+    def _put(self, x: np.ndarray) -> jax.Array:
+        return (jnp.asarray(x) if self._sharding is None
+                else jax.device_put(x, self._sharding))
 
     def pad(self, us: np.ndarray, rects: np.ndarray
             ) -> Tuple[int, jax.Array, jax.Array]:
@@ -339,9 +393,9 @@ class DevicePadder:
             rs0 = np.empty((2 * self.dim, Bb), np.float32)
             rs0[: self.dim] = np.inf
             rs0[self.dim:] = -np.inf
-            bufs = (jnp.zeros(Bb, jnp.int32), jnp.asarray(rs0))
-        us_b, r_b = self._fill(bufs[0], bufs[1], jnp.asarray(us_s),
-                               jnp.asarray(r_s), np.int32(B))
+            bufs = (self._put(np.zeros(Bb, np.int32)), self._put(rs0))
+        us_b, r_b = self._fill(bufs[0], bufs[1], self._put(us_s),
+                               self._put(r_s), np.int32(B))
         self._bufs[Bb] = (us_b, r_b)
         return Bb, us_b, r_b
 
@@ -435,44 +489,54 @@ class QueryEngine:
         # pipeline elides
         self._kb_hwm = 1
         self._padder = DevicePadder(self.dim)
+        # the device arrays every program reads, passed as an argument:
+        # a closed-over array would be embedded as a constant in each
+        # compiled program (one index copy per bucket and mode)
+        self._dev = {"side": self._side, "arena": self._arena,
+                     "qf": self._qfine, "qc": self._qcoarse,
+                     "ids": self._ids_row, "grid": self._grid}
         route = self._make_route()
         serve = self._make_routed_serve()
 
-        def fused(us, rects_soa, *, mode, kcap, kc=None):
-            qs, qe, pts, exc = route(us)
-            return serve(rects_soa, qs, qe, pts, exc, mode=mode,
+        def fused(dev, us, rects_soa, *, mode, kcap, kc=None):
+            qs, qe, pts, exc = route(dev, us)
+            return serve(dev, rects_soa, qs, qe, pts, exc, mode=mode,
                          kcap=kcap, kc=kc)
 
-        self._fused = jax.jit(fused, static_argnames=("mode", "kcap", "kc"))
-        self._route = jax.jit(route)
-        self._fused_routed = jax.jit(
+        def bind(fn, **jit_kw):
+            return _WithDeviceArrays(jax.jit(fn, **jit_kw), self._dev)
+
+        self._fused = bind(fused, static_argnames=("mode", "kcap", "kc"))
+        self._route = bind(route)
+        self._fused_routed = bind(
             serve, static_argnames=("mode", "kcap", "kc"))
-        self._prepare = jax.jit(self._make_prepare())
-        self._scan = jax.jit(self._make_scan())
-        self._count_scan = jax.jit(self._make_count_scan())
-        self._collect_scan = jax.jit(self._make_collect_scan())
+        self._prepare = bind(self._make_prepare())
+        self._scan = bind(self._make_scan(descent_scan_pallas))
+        self._count_scan = bind(self._make_scan(count_scan_pallas))
+        self._collect_scan = bind(self._make_scan(collect_scan_pallas,
+                                                  with_ids=True))
         self._collect_post = jax.jit(_collect_post, static_argnames=("kc",))
-        self._polygon_scan = jax.jit(self._make_polygon_scan(),
-                                     static_argnames=("ne",))
+        self._polygon_scan = bind(self._make_polygon_scan(),
+                                  static_argnames=("ne",))
 
     # ------------------------------------------------------------------
-    # jit closures (per-engine, so cache introspection is local)
+    # jitted programs over ``dev`` (per-engine, so cache introspection
+    # is local)
     # ------------------------------------------------------------------
 
     def _make_route(self):
         """Vertex -> (arena slice, point, excluded) routing: the
         rect-independent half of the fused trace, also jitted alone so
         the KNN radius-doubling driver hoists it out of its loop."""
-        side = self._side
-        arena = self._arena
 
-        def route(us):
+        def route(dev, us):
+            side, off = dev["side"], dev["arena"].entry_off
             tid = side.lookup(us)
             exc = side._excluded[us]
             valid = (tid >= 0) & ~exc
             t = jnp.maximum(tid, 0)
-            qs = jnp.where(valid, arena.entry_off[t], 0)
-            qe = jnp.where(valid, arena.entry_off[t + 1], 0)
+            qs = jnp.where(valid, off[t], 0)
+            qe = jnp.where(valid, off[t + 1], 0)
             return qs, qe, side._coords[us], exc
 
         return route
@@ -487,27 +551,24 @@ class QueryEngine:
         nt = self.n_tiles
         interpret = self._interpret
         impl = self._fused_impl
-        arena = self._arena
-        grid = self._grid
-        qf, qc = self._qfine, self._qcoarse
-        ids_row = self._ids_row
 
-        def serve(rects_soa, qs, qe, pts, exc, *, mode, kcap, kc=None):
+        def serve(dev, rects_soa, qs, qe, pts, exc, *, mode, kcap,
+                  kc=None):
             inr = jnp.ones(rects_soa.shape[1], dtype=bool)
             for a in range(dim):
                 inr = inr & (pts[:, a] >= rects_soa[a])
                 inr = inr & (pts[:, a] <= rects_soa[dim + a])
             forced = exc & inr               # Alg. 2 spatial-sink case
-            r16, r32 = quantize_rects(grid, rects_soa, dim)
+            r16, r32 = quantize_rects(dev["grid"], rects_soa, dim)
+            args = (dev["qf"], dev["qc"], dev["arena"].entries, dev["ids"],
+                    r16, r32, rects_soa, qs, qe)
             if impl == "pallas":
                 out, cnt = fused_serve_pallas(
-                    qf, qc, arena.entries, ids_row, r16, r32, rects_soa,
-                    qs, qe, mode=mode, kcap=kcap, nt=nt, dim=dim,
+                    *args, mode=mode, kcap=kcap, nt=nt, dim=dim,
                     interpret=interpret)
             else:
                 out, cnt = fused_serve_xla(
-                    qf, qc, arena.entries, ids_row, r16, r32, rects_soa,
-                    qs, qe, mode=mode, kcap=kcap, nt=nt, dim=dim)
+                    *args, mode=mode, kcap=kcap, nt=nt, dim=dim)
             if mode == "collect" and kc is not None:
                 # collect epilogue inside the same trace: top-kc ids +
                 # exact totals, so the host never receives the full
@@ -521,12 +582,11 @@ class QueryEngine:
         nt = self.n_tiles
         interpret = self._interpret
         dim = self.dim
-        side = self._side
-        arena = self._arena
 
-        def prepare(us, rects_soa):
+        def prepare(dev, us, rects_soa):
             # us (Bb,) int32; rects_soa (2*dim, Bb) f32
-            tid, valid, forced = side.route(us, rects_soa)
+            arena = dev["arena"]
+            tid, valid, forced = dev["side"].route(us, rects_soa)
             t = jnp.maximum(tid, 0)
             qs = jnp.where(valid, arena.entry_off[t], 0)
             qe = jnp.where(valid, arena.entry_off[t + 1], 0)
@@ -539,54 +599,26 @@ class QueryEngine:
 
         return prepare
 
-    def _make_scan(self):
+    def _make_scan(self, kernel, with_ids: bool = False):
+        """Two-phase leaf scan ``kernel(cand, entries[, ids], rects, qs,
+        qe)`` over the arena."""
         dim = self.dim
         interpret = self._interpret
-        arena = self._arena
 
-        def scan(cand_k, rects_soa, qs, qe):
-            return descent_scan_pallas(
-                cand_k, arena.entries, rects_soa, qs, qe,
-                dim=dim, interpret=interpret,
-            )
-
-        return scan
-
-    def _make_count_scan(self):
-        dim = self.dim
-        interpret = self._interpret
-        arena = self._arena
-
-        def scan(cand_k, rects_soa, qs, qe):
-            return count_scan_pallas(
-                cand_k, arena.entries, rects_soa, qs, qe,
-                dim=dim, interpret=interpret,
-            )
-
-        return scan
-
-    def _make_collect_scan(self):
-        dim = self.dim
-        interpret = self._interpret
-        arena = self._arena
-        ids_row = self._ids_row
-
-        def scan(cand_k, rects_soa, qs, qe):
-            return collect_scan_pallas(
-                cand_k, arena.entries, ids_row, rects_soa, qs, qe,
-                dim=dim, interpret=interpret,
-            )
+        def scan(dev, cand_k, rects_soa, qs, qe):
+            ids = (dev["ids"],) if with_ids else ()
+            return kernel(cand_k, dev["arena"].entries, *ids, rects_soa,
+                          qs, qe, dim=dim, interpret=interpret)
 
         return scan
 
     def _make_polygon_scan(self):
         dim = self.dim
         interpret = self._interpret
-        arena = self._arena
 
-        def scan(cand_k, rects_soa, lines_soa, qs, qe, *, ne):
+        def scan(dev, cand_k, rects_soa, lines_soa, qs, qe, *, ne):
             return polygon_scan_pallas(
-                cand_k, arena.entries, rects_soa, lines_soa, qs, qe,
+                cand_k, dev["arena"].entries, rects_soa, lines_soa, qs, qe,
                 ne=ne, dim=dim, interpret=interpret,
             )
 

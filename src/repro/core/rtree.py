@@ -39,7 +39,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..obs import span
 
@@ -278,57 +278,6 @@ class DeviceForest:
     n_tiles: int
 
 
-def _part1by1_jnp(x: jax.Array) -> jax.Array:
-    x = x & np.uint64(0xFFFF)
-    x = (x | (x << np.uint64(8))) & np.uint64(0x00FF00FF)
-    x = (x | (x << np.uint64(4))) & np.uint64(0x0F0F0F0F)
-    x = (x | (x << np.uint64(2))) & np.uint64(0x33333333)
-    x = (x | (x << np.uint64(1))) & np.uint64(0x55555555)
-    return x
-
-
-def _part1by2_jnp(x: jax.Array) -> jax.Array:
-    x = x & np.uint64(0x3FF)
-    x = (x | (x << np.uint64(16))) & np.uint64(0x030000FF)
-    x = (x | (x << np.uint64(8))) & np.uint64(0x0300F00F)
-    x = (x | (x << np.uint64(4))) & np.uint64(0x030C30C3)
-    x = (x | (x << np.uint64(2))) & np.uint64(0x09249249)
-    return x
-
-
-def _morton_code_jnp(centers: jax.Array, lo: jax.Array,
-                     hi: jax.Array) -> jax.Array:
-    """Device mirror of ``morton_code`` — identical float64 math, so the
-    codes (and hence the bulk-load order) are bit-identical to the host
-    build.  Must run under ``enable_x64``."""
-    dim = centers.shape[1]
-    span = jnp.where(hi > lo, hi - lo, 1.0)
-    unit = jnp.clip((centers.astype(jnp.float64) - lo) / span, 0.0, 1.0)
-    if dim == 2:
-        q = (unit * 0xFFFF).astype(jnp.uint64)
-        return _part1by1_jnp(q[:, 0]) | (_part1by1_jnp(q[:, 1]) << np.uint64(1))
-    elif dim == 3:
-        q = (unit * 0x3FF).astype(jnp.uint64)
-        return (
-            _part1by2_jnp(q[:, 0])
-            | (_part1by2_jnp(q[:, 1]) << np.uint64(1))
-            | (_part1by2_jnp(q[:, 2]) << np.uint64(2))
-        )
-    raise ValueError(f"dim {dim} unsupported")
-
-
-@jax.jit
-def _morton_key_jit(soa: jax.Array, lo: jax.Array, hi: jax.Array
-                    ) -> jax.Array:
-    """(P,) uint64 sort keys ``morton_code << 32 | entry_index``, fused
-    into one pass over the entry planes.  Runs under ``enable_x64``."""
-    dim = soa.shape[0] // 2
-    centers = ((soa[:dim] + soa[dim:]) * 0.5).T       # (P, dim) f32
-    code = _morton_code_jnp(centers, lo, hi)
-    P = soa.shape[1]
-    return (code << np.uint64(32)) | jnp.arange(P, dtype=jnp.uint64)
-
-
 @partial(jax.jit, static_argnames=("L",), donate_argnums=(3,))
 def _bucket_sort_step(key, starts, cnts, order, *, L: int):
     P = key.shape[0]
@@ -393,9 +342,9 @@ def build_forest_device(
     """Bulk-load a forest on the accelerator (same contract — and same
     resulting arrays, bit for bit — as :func:`build_forest`).
 
-    The pipeline stays device-resident end to end: Morton encode (jnp,
-    float64 math identical to host), one bucketed ``(tree, code)``
-    values-only key sort, then the segmented-MBR reduction of
+    The pipeline: Morton keys encoded on the host (``build_forest``'s
+    float64 math, so the order is the host build's), one bucketed
+    ``(tree, code)`` values-only key sort on device, then the segmented-MBR reduction of
     :mod:`repro.kernels.forest_build` builds every R-tree node level and
     the query engines' fine/coarse tile pyramid.  The returned forest
     carries host mirrors of every array (so ``query_host`` and
@@ -460,13 +409,15 @@ def build_forest_device(
             jnp.asarray(np_inert_plane(dim, 1)),   # padding gather target
         ], axis=1)                                          # (2*dim, P+1)
         if P:
+            # sort keys ``morton_code << 32 | entry index`` from the host
+            # build's own float64 math: a TPU emulates float64, and its
+            # rounding moved codes across quantization steps
+            centers = (boxes[:, :dim] + boxes[:, dim:]) * 0.5
+            key = ((morton_code(centers, extent) << np.uint64(32))
+                   | np.arange(P, dtype=np.uint64))
             with enable_x64():
-                key = _morton_key_jit(
-                    soa_ext[:, :P],
-                    jnp.asarray(extent[:dim], jnp.float64),
-                    jnp.asarray(extent[dim:], jnp.float64),
-                )
-                order = _bucketed_tree_sort(key, entry_off, counts)
+                order = _bucketed_tree_sort(
+                    jnp.asarray(key), entry_off, counts)
             # one gather builds the permuted AND padded serving plane
             order_pad = jnp.concatenate([
                 order, jnp.full((Pp - P,), P, jnp.int32)])

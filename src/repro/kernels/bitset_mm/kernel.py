@@ -36,14 +36,14 @@ def _bitset_mm_kernel(a_ref, r_ref, o_ref):
 
     @pl.when(jw == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    a = a_ref[...][:, 0]            # (TI,) uint32 — one word-column of A
+    a = a_ref[...]                  # (TI, 1) uint32 — one word-column of A
     r = r_ref[...]                  # (32, TW) uint32
     acc = o_ref[...]                # (TI, TW)
     for k in range(32):
-        bit = ((a >> jnp.uint32(k)) & jnp.uint32(1)) > 0      # (TI,)
-        acc = acc | jnp.where(bit[:, None], r[k][None, :], jnp.uint32(0))
+        bit = ((a >> jnp.uint32(k)) & jnp.uint32(1)) != 0     # (TI, 1)
+        acc = acc | jnp.where(bit, r[k:k + 1, :], jnp.uint32(0))
     o_ref[...] = acc
 
 
@@ -61,14 +61,17 @@ def bitset_mm_pallas(
     assert dj == Wd * 32, (dj, Wd)
     assert d % ti == 0 and W % tw == 0, (d, W)
     grid = (d // ti, W // tw, Wd)
+    # A enters word-column-major as (Wd, d, 1): a step's (ti, 1) block
+    # spans the full trailing dim, which the TPU block rule accepts (a
+    # (ti, 1) block of the (d, Wd) matrix would not)
     return pl.pallas_call(
         _bitset_mm_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ti, 1), lambda i, w, jw: (i, jw)),
+            pl.BlockSpec((None, ti, 1), lambda i, w, jw: (jw, i, 0)),
             pl.BlockSpec((32, tw), lambda i, w, jw: (jw, w)),
         ],
         out_specs=pl.BlockSpec((ti, tw), lambda i, w, jw: (i, w)),
         out_shape=jax.ShapeDtypeStruct((d, W), jnp.uint32),
         interpret=interpret,
-    )(a_bits, r_bits)
+    )(a_bits.T[:, :, None], r_bits)

@@ -31,7 +31,7 @@ scans over the *same* compacted candidate lists phase 1 produces:
 Every kernel has a dense jnp reference (``*_ref``) scanning the whole
 arena — the exactness oracle for unit tests and a fused XLA fallback.
 All run under ``interpret=True`` on CPU; on TPU the same calls compile
-to real kernels.
+to real kernels (query tile on sublanes, as in :mod:`.kernel`).
 """
 
 from __future__ import annotations
@@ -45,22 +45,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel import TB, TP
+from .kernel import TB, TP, box_hits, query_rows, query_specs
 
 # payload-id sentinel for collect padding/misses: sorts after every real
 # vertex id and survives the int32 round trip
 ID_SENTINEL = np.int32(np.iinfo(np.int32).max)
 
 
-def _hit_mask(e, q, qs, qe, tile, *, dim: int, tp: int):
+def _hit_mask(e, q, qse, tile, *, dim: int, tp: int):
     """(TB, TP) exact per-entry test shared by the scan variants:
     arena-slice membership AND box intersection."""
     gidx = tile * tp + jax.lax.broadcasted_iota(jnp.int32, (1, tp), 1)
-    ok = (gidx >= qs) & (gidx < qe)
-    for a in range(dim):
-        ok = ok & (e[a][None, :] <= q[dim + a][:, None])
-        ok = ok & (e[dim + a][None, :] >= q[a][:, None])
-    return ok
+    ok = (gidx >= qse[:, 0:1]) & (gidx < qse[:, 1:2])
+    return ok & box_hits(e, q, dim)
 
 
 def _dup_slot(cand_ref, i, k):
@@ -74,17 +71,17 @@ def _dup_slot(cand_ref, i, k):
 # Count
 # --------------------------------------------------------------------------
 
-def _count_kernel(cand_ref, e_ref, q_ref, qs_ref, qe_ref, o_ref, *,
+def _count_kernel(cand_ref, e_ref, q_ref, qse_ref, o_ref, *,
                   dim: int, tp: int):
     i, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    ok = _hit_mask(e_ref[...], q_ref[...], qs_ref[...][:, None],
-                   qe_ref[...][:, None], cand_ref[i, k], dim=dim, tp=tp)
-    cnt = jnp.sum(ok, axis=1).astype(jnp.int32)
+    ok = _hit_mask(e_ref[...], q_ref[...], qse_ref[...], cand_ref[i, k],
+                   dim=dim, tp=tp)
+    cnt = jnp.sum(ok.astype(jnp.int32), axis=1, keepdims=True)
     o_ref[...] = o_ref[...] + jnp.where(_dup_slot(cand_ref, i, k), 0, cnt)
 
 
@@ -120,18 +117,17 @@ def count_scan_pallas(
         grid=(nb, K),
         in_specs=[
             pl.BlockSpec((two_dim, tp), lambda i, k, cand: (0, cand[i, k])),
-            pl.BlockSpec((two_dim, tb), lambda i, k, cand: (0, i)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+            *query_specs(tb, two_dim, lambda i, k, cand: i),
         ],
-        out_specs=pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+        out_specs=pl.BlockSpec((tb, 1), lambda i, k, cand: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_count_kernel, dim=dim, tp=tp),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
-    )(cand, entries_soa, rects_soa, qstart, qend)
+    )(cand, entries_soa, *query_rows(rects_soa, qstart, qend))
+    return out[:, 0]
 
 
 def count_scan_ref(entries_soa, rects_soa, qstart, qend, *, dim: int = 2,
@@ -150,11 +146,11 @@ def count_scan_ref(entries_soa, rects_soa, qstart, qend, *, dim: int = 2,
 # Collect
 # --------------------------------------------------------------------------
 
-def _collect_kernel(cand_ref, e_ref, ids_ref, q_ref, qs_ref, qe_ref, o_ref,
+def _collect_kernel(cand_ref, e_ref, ids_ref, q_ref, qse_ref, o_ref,
                     *, dim: int, tp: int):
     i, k = pl.program_id(0), pl.program_id(1)
-    ok = _hit_mask(e_ref[...], q_ref[...], qs_ref[...][:, None],
-                   qe_ref[...][:, None], cand_ref[i, k], dim=dim, tp=tp)
+    ok = _hit_mask(e_ref[...], q_ref[...], qse_ref[...], cand_ref[i, k],
+                   dim=dim, tp=tp)
     ok = ok & ~_dup_slot(cand_ref, i, k)
     ids = ids_ref[...]                       # (1, tp) payload ids
     o_ref[...] = jnp.where(ok, ids, ID_SENTINEL)
@@ -191,9 +187,7 @@ def collect_scan_pallas(
         in_specs=[
             pl.BlockSpec((two_dim, tp), lambda i, k, cand: (0, cand[i, k])),
             pl.BlockSpec((1, tp), lambda i, k, cand: (0, cand[i, k])),
-            pl.BlockSpec((two_dim, tb), lambda i, k, cand: (0, i)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+            *query_specs(tb, two_dim, lambda i, k, cand: i),
         ],
         out_specs=pl.BlockSpec((tb, tp), lambda i, k, cand: (i, k)),
     )
@@ -202,7 +196,7 @@ def collect_scan_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K * tp), jnp.int32),
         interpret=interpret,
-    )(cand, entries_soa, ids_soa, rects_soa, qstart, qend)
+    )(cand, entries_soa, ids_soa, *query_rows(rects_soa, qstart, qend))
 
 
 def collect_scan_ref(entries_soa, ids_soa, rects_soa, qstart, qend, *,
@@ -221,29 +215,30 @@ def collect_scan_ref(entries_soa, ids_soa, rects_soa, qstart, qend, *,
 # Polygon (half-plane postfilter in the leaf scan)
 # --------------------------------------------------------------------------
 
-def _polygon_kernel(cand_ref, e_ref, q_ref, l_ref, qs_ref, qe_ref, o_ref, *,
+def _polygon_kernel(cand_ref, e_ref, l_ref, q_ref, qse_ref, o_ref, *,
                     dim: int, tp: int, ne: int):
     i, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
     e = e_ref[...]
-    ok = _hit_mask(e, q_ref[...], qs_ref[...][:, None],
-                   qe_ref[...][:, None], cand_ref[i, k], dim=dim, tp=tp)
+    ok = _hit_mask(e, q_ref[...], qse_ref[...], cand_ref[i, k], dim=dim,
+                   tp=tp)
     # half-plane postfilter on the entry point (entries are degenerate
     # point boxes, so the min plane is the coordinate); same f32
     # mul/add/compare sequence as points_in_polygon_region
-    x = e[0][None, :]
-    y = e[1][None, :]
-    lines = l_ref[...]                       # (3*ne, TB)
+    x = e[0:1, :]
+    y = e[1:2, :]
+    lines = l_ref[...]                       # (TB, 3*ne)
     for hp in range(ne):
-        A = lines[hp][:, None]
-        Bc = lines[ne + hp][:, None]
-        C = lines[2 * ne + hp][:, None]
+        A = lines[:, hp:hp + 1]
+        Bc = lines[:, ne + hp:ne + hp + 1]
+        C = lines[:, 2 * ne + hp:2 * ne + hp + 1]
         ok = ok & ((A * x + Bc * y) <= C)
-    o_ref[...] = o_ref[...] | jnp.any(ok, axis=1).astype(jnp.int32)
+    o_ref[...] = o_ref[...] | jnp.max(ok.astype(jnp.int32), axis=1,
+                                      keepdims=True)
 
 
 @functools.partial(
@@ -279,19 +274,18 @@ def polygon_scan_pallas(
         grid=(nb, K),
         in_specs=[
             pl.BlockSpec((two_dim, tp), lambda i, k, cand: (0, cand[i, k])),
-            pl.BlockSpec((two_dim, tb), lambda i, k, cand: (0, i)),
-            pl.BlockSpec((3 * ne, tb), lambda i, k, cand: (0, i)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+            pl.BlockSpec((tb, 3 * ne), lambda i, k, cand: (i, 0)),
+            *query_specs(tb, two_dim, lambda i, k, cand: i),
         ],
-        out_specs=pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+        out_specs=pl.BlockSpec((tb, 1), lambda i, k, cand: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_polygon_kernel, dim=dim, tp=tp, ne=ne),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
-    )(cand, entries_soa, rects_soa, lines_soa, qstart, qend)
+    )(cand, entries_soa, lines_soa.T, *query_rows(rects_soa, qstart, qend))
+    return out[:, 0]
 
 
 def polygon_scan_ref(entries_soa, rects_soa, lines_soa, qstart, qend, *,

@@ -27,9 +27,12 @@ tile-shaped kernel:
   idempotent — exactness never depends on the mask.
 
 Both kernels run under ``interpret=True`` on CPU; on TPU the same calls
-compile to real kernels (the coarse plane's narrow lane blocks are an
-interpret-mode convenience — pad ``COARSE_GROUP`` to 1 on TPU to keep
-blocks lane-aligned if the compiler objects).
+compile to real kernels.  Every block obeys the TPU's (8, 128) rule:
+the query tile sits on sublanes (``kernel.query_rows``), the coarse
+plane enters repeated to fine-tile width so its block is lane-aligned
+with the fine block, and the prune mask is written as
+``(B // tb, 1, NTp)`` so each step's ``(1, tpt)`` row spans a full
+middle dimension.
 """
 
 from __future__ import annotations
@@ -44,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kernel import TB, TP
+from .kernel import TB, TP, box_hits, query_rows, query_specs
 
 TPT = 128        # fine-tile lanes per prune-kernel block
 COARSE_GROUP = 8  # leaf tiles per coarse pyramid node
@@ -108,39 +111,27 @@ def build_tile_pyramid(
 # Phase 1: hierarchical prune
 # --------------------------------------------------------------------------
 
-def _prune_kernel(f_ref, c_ref, q_ref, qs_ref, qe_ref, o_ref, *, dim: int,
-                  tpt: int, tp: int, group: int):
+def _prune_kernel(f_ref, c_ref, q_ref, qse_ref, o_ref, *, dim: int,
+                  tpt: int, tp: int):
     j = pl.program_id(1)
-    q = q_ref[...]                       # (2*dim, TB)
-    qs = qs_ref[...][:, None]            # (TB, 1)
-    qe = qe_ref[...][:, None]
+    q = q_ref[...]                       # (TB, 2*dim)
 
     # -- coarse level: internal MBRs gate the whole block ------------------
-    c = c_ref[...]                       # (2*dim, tpt//group)
-    cok = jnp.ones((q.shape[1], c.shape[1]), dtype=bool)
-    for a in range(dim):
-        cok = cok & (c[a][None, :] <= q[dim + a][:, None])
-        cok = cok & (c[dim + a][None, :] >= q[a][:, None])
+    cok = box_hits(c_ref[...], q, dim)   # (TB, tpt): each tile's group MBR
+    any_c = jnp.max(cok.astype(jnp.int32)) > 0
 
-    @pl.when(jnp.any(cok))
+    @pl.when(any_c)
     def _descend():
-        f = f_ref[...]                   # (2*dim, tpt)
         gidx = j * tpt + jax.lax.broadcasted_iota(jnp.int32, (1, tpt), 1)
         # arena-slice overlap: fine tile g covers entries [g*tp, g*tp+tp)
-        ok = (gidx * tp < qe) & (gidx * tp + tp > qs)     # (TB, tpt)
-        for a in range(dim):
-            ok = ok & (f[a][None, :] <= q[dim + a][:, None])
-            ok = ok & (f[dim + a][None, :] >= q[a][:, None])
-        ncg = tpt // group
-        cexp = jnp.broadcast_to(
-            cok[:, :, None], (cok.shape[0], ncg, group)
-        ).reshape(cok.shape[0], tpt)
-        ok = ok & cexp
-        o_ref[...] = jnp.any(ok, axis=0).astype(jnp.int32)[None, :]
+        ok = ((gidx * tp < qse_ref[:, 1:2])
+              & (gidx * tp + tp > qse_ref[:, 0:1]))       # (TB, tpt)
+        ok = ok & box_hits(f_ref[...], q, dim) & cok
+        o_ref[...] = jnp.max(ok.astype(jnp.int32), axis=0, keepdims=True)
 
-    @pl.when(~jnp.any(cok))
+    @pl.when(~any_c)
     def _pruned():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
 @functools.partial(
@@ -168,22 +159,20 @@ def prune_tiles_pallas(
     assert coarse_soa.shape == (two_dim, ntp // group)
     nb = B // tb
     grid = (nb, ntp // tpt)
-    return pl.pallas_call(
-        functools.partial(
-            _prune_kernel, dim=dim, tpt=tpt, tp=tp, group=group
-        ),
+    mask = pl.pallas_call(
+        functools.partial(_prune_kernel, dim=dim, tpt=tpt, tp=tp),
         grid=grid,
         in_specs=[
             pl.BlockSpec((two_dim, tpt), lambda i, j: (0, j)),
-            pl.BlockSpec((two_dim, tpt // group), lambda i, j: (0, j)),
-            pl.BlockSpec((two_dim, tb), lambda i, j: (0, i)),
-            pl.BlockSpec((tb,), lambda i, j: (i,)),
-            pl.BlockSpec((tb,), lambda i, j: (i,)),
+            pl.BlockSpec((two_dim, tpt), lambda i, j: (0, j)),
+            *query_specs(tb, two_dim, lambda i, j: i),
         ],
-        out_specs=pl.BlockSpec((1, tpt), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nb, ntp), jnp.int32),
+        out_specs=pl.BlockSpec((None, 1, tpt), lambda i, j: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((nb, 1, ntp), jnp.int32),
         interpret=interpret,
-    )(fine_soa, coarse_soa, rects_soa, qstart, qend)
+    )(fine_soa, jnp.repeat(coarse_soa, group, axis=1),
+      *query_rows(rects_soa, qstart, qend))
+    return mask.reshape(nb, ntp)
 
 
 def prune_tiles_ref(fine_soa, coarse_soa, rects_soa, qstart, qend, *,
@@ -211,25 +200,20 @@ def prune_tiles_ref(fine_soa, coarse_soa, rects_soa, qstart, qend, *,
 # Phase 2: masked leaf scan over compacted candidate tiles
 # --------------------------------------------------------------------------
 
-def _scan_kernel(cand_ref, e_ref, q_ref, qs_ref, qe_ref, o_ref, *, dim: int,
+def _scan_kernel(cand_ref, e_ref, q_ref, qse_ref, o_ref, *, dim: int,
                  tp: int):
     i, k = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    e = e_ref[...]                        # (2*dim, TP) — the candidate tile
-    q = q_ref[...]                        # (2*dim, TB)
     tile = cand_ref[i, k]
     gidx = tile * tp + jax.lax.broadcasted_iota(jnp.int32, (1, tp), 1)
-    qs = qs_ref[...][:, None]
-    qe = qe_ref[...][:, None]
-    ok = (gidx >= qs) & (gidx < qe)       # (TB, TP)
-    for a in range(dim):
-        ok = ok & (e[a][None, :] <= q[dim + a][:, None])
-        ok = ok & (e[dim + a][None, :] >= q[a][:, None])
-    o_ref[...] = o_ref[...] | jnp.any(ok, axis=1).astype(jnp.int32)
+    ok = (gidx >= qse_ref[:, 0:1]) & (gidx < qse_ref[:, 1:2])  # (TB, TP)
+    ok = ok & box_hits(e_ref[...], q_ref[...], dim)
+    o_ref[...] = o_ref[...] | jnp.max(ok.astype(jnp.int32), axis=1,
+                                      keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("dim", "interpret", "tb", "tp"))
@@ -264,15 +248,14 @@ def descent_scan_pallas(
         grid=(nb, K),
         in_specs=[
             pl.BlockSpec((two_dim, tp), lambda i, k, cand: (0, cand[i, k])),
-            pl.BlockSpec((two_dim, tb), lambda i, k, cand: (0, i)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
-            pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+            *query_specs(tb, two_dim, lambda i, k, cand: i),
         ],
-        out_specs=pl.BlockSpec((tb,), lambda i, k, cand: (i,)),
+        out_specs=pl.BlockSpec((tb, 1), lambda i, k, cand: (i, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_scan_kernel, dim=dim, tp=tp),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
-    )(cand, entries_soa, rects_soa, qstart, qend)
+    )(cand, entries_soa, *query_rows(rects_soa, qstart, qend))
+    return out[:, 0]

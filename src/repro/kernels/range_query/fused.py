@@ -13,22 +13,28 @@ module makes the device path agree:
   slack so float32 scaling error can never round inward).  The
   quantized intersection test is therefore a provable superset of the
   float32 truth: pruning stays sound, the final leaf predicate stays
-  exact f32, and the fine plane moves half the bytes through VMEM.
+  exact f32.  (The int16 plane halves HBM bytes; in VMEM its 4 rows
+  pad to a 16-row tile, the same footprint as 8 int32 rows.)
   Padding (±inf) bounds map to reserved sentinel codes that fail both
   halves of the intersect test, so padding tiles can never activate.
 
 * **The megakernel** (:func:`fused_serve_pallas`): ONE ``pallas_call``
   over grid ``(B // TB,)``.  Each step holds its query tile's rects
-  (quantized + exact), the whole quantized pyramid (VMEM-resident —
-  ~64 KB at a million venues), and the entry arena left in HBM/ANY.
-  In-kernel it (1) evaluates the hierarchical coarse→fine prune, (2)
-  compacts the surviving leaf tiles into an ascending worklist via a
-  lane prefix-sum (no host compaction, no materialized candidate
-  matrix), and (3) walks the worklist with double-buffered DMA — the
-  next tile's HBM→VMEM copy is in flight while the current tile's
-  exact f32 predicate evaluates.  A ``mode`` flag selects the epilogue
-  — boolean OR, exact count, or collect (ids-or-sentinel written per
-  worklist slot) — so one kernel serves ``query/count/collect_batch``.
+  (quantized + exact), the whole quantized pyramid as full-array VMEM
+  blocks, and the entry arena left in HBM/ANY.  The pyramid is not
+  small: its 4 coordinate rows pad to a full (16, 128) int16 / (8, 128)
+  int32 tile, so the fine plane and the coarse plane (repeated to fine
+  width) take ~0.7 MB each per buffer at Gowalla x50 (22,272 tile
+  lanes), ~0.25 MB each at a million venues.  In-kernel it (1)
+  evaluates the hierarchical coarse∧fine prune, (2) counts the
+  surviving leaf tiles, and (3) walks them in ascending order — slot
+  k+1's tile is the smallest active lane above slot k's (a masked lane
+  min, no host compaction, no materialized candidate matrix) — with
+  double-buffered DMA: the next tile's HBM→VMEM copy is in flight while
+  the current tile's exact f32 predicate evaluates.  A ``mode`` flag
+  selects the epilogue — boolean OR, exact count, or collect
+  (ids-or-sentinel written per worklist slot) — so one kernel serves
+  ``query/count/collect_batch``.
 
 * **The fused XLA path** (:func:`fused_serve_xla`): the same
   route→prune→compact→scan semantics as one fused XLA program (dense
@@ -61,7 +67,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .analytics import ID_SENTINEL
 from .descent import COARSE_GROUP
-from .kernel import TB, TP
+from .kernel import TB, TP, box_hits, query_rows, query_specs
 
 # int16 fine-plane code space: finite bounds clip to [I16_LO, I16_HI];
 # the values just outside are reserved for ±inf padding so an inert
@@ -79,6 +85,7 @@ _GRID32 = float(2 ** 20)  # coarse grid cells
 # Quantization (outward-rounded, provably conservative)
 # --------------------------------------------------------------------------
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class QuantGrid:
     """Per-axis affine maps onto the int16 / int32 code grids.
@@ -224,70 +231,45 @@ def compact_ascending(mask: jax.Array, nt: int
 # The megakernel (one pallas_call: prune + compact + double-buffered scan)
 # --------------------------------------------------------------------------
 
-def _prefix_lanes(x: jax.Array) -> jax.Array:
-    """Inclusive prefix sum along the lane axis of a (1, N) int32 —
-    log2(N) shifted adds (static Python loop, Mosaic-friendly)."""
-    n = x.shape[1]
-    d = 1
-    while d < n:
-        x = x + jnp.pad(x, ((0, 0), (d, 0)))[:, :n]
-        d <<= 1
-    return x
-
-
-def _fused_kernel(qf_ref, qc_ref, r16_ref, r32_ref, q_ref, qs_ref, qe_ref,
-                  e_any, *rest, mode: str, kcap: int, nt: int, dim: int,
-                  tp: int, group: int):
+def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
+                  mode: str, kcap: int, dim: int, tp: int):
     if mode == "collect":
         ids_any, o_ref, cnt_ref, ebuf, esem, ibuf, isem = rest
     else:
         o_ref, cnt_ref, ebuf, esem = rest
         ids_any = ibuf = isem = None
 
-    qs = qs_ref[...][:, None]               # (TB, 1)
-    qe = qe_ref[...][:, None]
+    qs = qse_ref[:, 0:1]                    # (TB, 1)
+    qe = qse_ref[:, 1:2]
 
     # ---- phase 1: quantized hierarchical prune (all in VMEM) ----------
-    qf = qf_ref[...]                        # (2*dim, NTp) int16
-    qc = qc_ref[...]                        # (2*dim, NCp) int32
-    r16 = r16_ref[...]                      # (2*dim, TB) int16
-    r32 = r32_ref[...]
+    qf = qf_ref[...].astype(jnp.int32)      # (2*dim, NTp) int16 codes
+    qc = qc_ref[...]                        # (2*dim, NTp) int32 coarse
+    rq = rq_ref[...]                        # (TB, 4*dim) [r16 | r32]
     ntp = qf.shape[1]
-    gidx = jax.lax.broadcasted_iota(jnp.int32, (1, ntp), 1)
-    ok = (gidx * tp < qe) & (gidx * tp + tp > qs)       # (TB, NTp)
-    for a in range(dim):
-        ok = ok & (qf[a][None, :] <= r16[dim + a][:, None])
-        ok = ok & (qf[dim + a][None, :] >= r16[a][:, None])
-    cok = jnp.ones((qs.shape[0], qc.shape[1]), dtype=bool)
-    for a in range(dim):
-        cok = cok & (qc[a][None, :] <= r32[dim + a][:, None])
-        cok = cok & (qc[dim + a][None, :] >= r32[a][:, None])
-    ncg = ntp // group
-    cexp = jnp.broadcast_to(
-        cok[:, :ncg, None], (cok.shape[0], ncg, group)
-    ).reshape(cok.shape[0], ncg * group)
-    ok = ok & cexp
-    act = jnp.any(ok, axis=0)[None, :]                  # (1, NTp) bool
-
-    # ---- phase 2: in-kernel compaction (lane prefix sum) --------------
-    csum = _prefix_lanes(act.astype(jnp.int32))         # (1, NTp)
-    cnt = csum[0, ntp - 1]
-    cnt_ref[0] = cnt
-    n = jnp.minimum(cnt, kcap)
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, ntp), 1)
+    ok = (lanes * tp < qe) & (lanes * tp + tp > qs)     # (TB, NTp)
+    ok = ok & box_hits(qf, rq[:, :2 * dim], dim)
+    ok = ok & box_hits(qc, rq[:, 2 * dim:], dim)
+    act = jnp.max(ok.astype(jnp.int32), axis=0, keepdims=True) > 0
 
-    def tile_of(s):
-        """Worklist slot s -> ascending s-th active tile id (scalar)."""
-        match = act & (csum == s + 1)
-        return jnp.min(jnp.where(match, lanes, ntp)).astype(jnp.int32)
+    # ---- phase 2: worklist size; slots are the actives in ascending
+    # order, each found from the previous one by a masked lane min ------
+    cnt = jnp.sum(act.astype(jnp.int32))
+    cnt_ref[...] = jnp.full(cnt_ref.shape, cnt, jnp.int32)
+    n = jnp.minimum(cnt, kcap)
+
+    def next_tile(t):
+        """Smallest active tile id > t (``ntp`` when none is left)."""
+        return jnp.min(jnp.where(act & (lanes > t), lanes, ntp))
 
     # ---- phase 3: double-buffered masked scan over the worklist -------
-    q = q_ref[...]                          # (2*dim, TB) exact f32 rects
+    q = q_ref[...]                          # (TB, 2*dim) exact f32 rects
 
-    def dma(k, slot):
-        """The (deterministic) copy descriptors for worklist slot k —
-        rebuilt identically at start and wait time."""
-        off = pl.multiple_of(tile_of(k) * tp, tp)
+    def dma(t, slot):
+        """The (deterministic) copy descriptors for tile t into buffer
+        ``slot`` — rebuilt identically at start and wait time."""
+        off = pl.multiple_of(t * tp, tp)
         cps = [pltpu.make_async_copy(
             e_any.at[:, pl.ds(off, tp)], ebuf.at[slot], esem.at[slot])]
         if mode == "collect":
@@ -296,42 +278,43 @@ def _fused_kernel(qf_ref, qc_ref, r16_ref, r32_ref, q_ref, qs_ref, qe_ref,
                 isem.at[slot]))
         return cps
 
+    t0 = next_tile(-1)
+
     @pl.when(n > 0)
     def _first():
-        for cp in dma(0, 0):
+        for cp in dma(t0, 0):
             cp.start()
 
     if mode == "collect":
         o_ref[...] = jnp.full(o_ref.shape, ID_SENTINEL, dtype=jnp.int32)
 
-    def body(k, acc):
+    def body(k, carry):
+        t, acc = carry
         slot = jax.lax.rem(k, 2)
+        t_next = next_tile(t)
 
         @pl.when(k + 1 < n)
         def _next():
-            for cp in dma(k + 1, jax.lax.rem(k + 1, 2)):
+            for cp in dma(t_next, 1 - slot):
                 cp.start()
 
-        for cp in dma(k, slot):
+        for cp in dma(t, slot):
             cp.wait()
-        e = ebuf[slot]                      # (2*dim, TP) exact f32
-        t = tile_of(k)
         g = t * tp + jax.lax.broadcasted_iota(jnp.int32, (1, tp), 1)
-        hit = (g >= qs) & (g < qe)          # (TB, TP) exact leaf test
-        for a in range(dim):
-            hit = hit & (e[a][None, :] <= q[dim + a][:, None])
-            hit = hit & (e[dim + a][None, :] >= q[a][:, None])
+        hit = (g >= qs) & (g < qe) & box_hits(ebuf[slot], q, dim)
         if mode == "reach":
-            return acc | jnp.any(hit, axis=1).astype(jnp.int32)
-        if mode == "count":
-            return acc + jnp.sum(hit, axis=1).astype(jnp.int32)
-        ids = ibuf[slot][0][None, :]        # (1, TP)
-        vals = jnp.where(hit, ids, ID_SENTINEL)
-        o_ref[:, pl.ds(pl.multiple_of(k * tp, tp), tp)] = vals
-        return acc
+            acc = acc | jnp.max(hit.astype(jnp.int32), axis=1,
+                                keepdims=True)
+        elif mode == "count":
+            acc = acc + jnp.sum(hit.astype(jnp.int32), axis=1,
+                                keepdims=True)
+        else:
+            vals = jnp.where(hit, ibuf[slot], ID_SENTINEL)
+            o_ref[:, pl.ds(pl.multiple_of(k * tp, tp), tp)] = vals
+        return t_next, acc
 
-    acc = jax.lax.fori_loop(
-        0, n, body, jnp.zeros((qs.shape[0],), jnp.int32))
+    _, acc = jax.lax.fori_loop(
+        0, n, body, (t0, jnp.zeros((q.shape[0], 1), jnp.int32)))
     if mode != "collect":
         o_ref[...] = acc
 
@@ -365,6 +348,13 @@ def fused_serve_pallas(
     * ``cnt`` — (B // tb,) int32 true candidate-tile counts.  Any
       value > ``kcap`` means the scan was truncated and the caller must
       re-run at a larger capacity (the engine's ratchet).
+
+    Block layout (TPU (8, 128) rule): the pyramid planes are full-array
+    VMEM blocks, the coarse plane repeated to fine-tile width so both
+    prune tests share one lane axis; the query tile sits on sublanes —
+    ``(tb, 4*dim)`` int32 quantized rect rows (int16 codes widened
+    exactly), ``(tb, 2*dim)`` f32 rect rows, ``(tb, 2)`` arena slices —
+    and the per-query outputs are ``(tb, 1)`` columns.
     """
     two_dim, P = entries_soa.shape
     _, B = rects_soa.shape
@@ -376,45 +366,44 @@ def fused_serve_pallas(
     nb = B // tb
     kcap = max(int(kcap), 1)
 
+    rq = jnp.concatenate([r16.astype(jnp.int32), r32], axis=0).T
     in_specs = [
         pl.BlockSpec((two_dim, ntp), lambda i: (0, 0)),
-        pl.BlockSpec((two_dim, ntp // group), lambda i: (0, 0)),
-        pl.BlockSpec((two_dim, tb), lambda i: (0, i)),
-        pl.BlockSpec((two_dim, tb), lambda i: (0, i)),
-        pl.BlockSpec((two_dim, tb), lambda i: (0, i)),
-        pl.BlockSpec((tb,), lambda i: (i,)),
-        pl.BlockSpec((tb,), lambda i: (i,)),
-        pl.BlockSpec(memory_space=pltpu.ANY),           # entry arena
+        pl.BlockSpec((two_dim, ntp), lambda i: (0, 0)),
+        pl.BlockSpec((tb, 2 * two_dim), lambda i: (i, 0)),
+        *query_specs(tb, two_dim, lambda i: i),
+        pl.BlockSpec(memory_space=pl.ANY),              # entry arena
     ]
-    args = [qfine, qcoarse, r16, r32, rects_soa, qstart, qend,
-            entries_soa]
+    args = [qfine, jnp.repeat(qcoarse, group, axis=1), rq,
+            *query_rows(rects_soa, qstart, qend), entries_soa]
     scratch = [
         pltpu.VMEM((2, two_dim, tp), jnp.float32),      # tile buffers
         pltpu.SemaphoreType.DMA((2,)),
     ]
     if mode == "collect":
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         args.append(ids_soa)
         scratch += [pltpu.VMEM((2, 1, tp), jnp.int32),
                     pltpu.SemaphoreType.DMA((2,))]
         out_spec = pl.BlockSpec((tb, kcap * tp), lambda i: (i, 0))
         out_shape = jax.ShapeDtypeStruct((B, kcap * tp), jnp.int32)
     else:
-        out_spec = pl.BlockSpec((tb,), lambda i: (i,))
-        out_shape = jax.ShapeDtypeStruct((B,), jnp.int32)
+        out_spec = pl.BlockSpec((tb, 1), lambda i: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((B, 1), jnp.int32)
 
     out, cnt = pl.pallas_call(
-        functools.partial(
-            _fused_kernel, mode=mode, kcap=kcap, nt=nt, dim=dim, tp=tp,
-            group=group),
+        functools.partial(_fused_kernel, mode=mode, kcap=kcap, dim=dim,
+                          tp=tp),
         grid=(nb,),
         in_specs=in_specs,
-        out_specs=[out_spec, pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[out_shape, jax.ShapeDtypeStruct((nb,), jnp.int32)],
+        out_specs=[out_spec, pl.BlockSpec((tb, 1), lambda i: (i, 0))],
+        out_shape=[out_shape, jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         scratch_shapes=scratch,
         interpret=interpret,
     )(*args)
-    return out, cnt
+    if mode != "collect":
+        out = out[:, 0]
+    return out, cnt[::tb, 0]
 
 
 # --------------------------------------------------------------------------

@@ -42,10 +42,9 @@ def _segment_bag_kernel(idx_ref, seg_ref, w_ref, table_ref, o_ref, *, tl: int):
     for k in range(tl):
         idx = idx_ref[k]
         seg = seg_ref[k]
-        row = pl.load(table_ref, (pl.dslice(idx, 1), slice(None)))  # (1, D)
+        row = table_ref[pl.ds(idx, 1), :]              # (1, D)
         w = w_ref[k].astype(row.dtype)
-        cur = pl.load(o_ref, (pl.dslice(seg, 1), slice(None)))
-        pl.store(o_ref, (pl.dslice(seg, 1), slice(None)), cur + w * row)
+        o_ref[pl.ds(seg, 1), :] = o_ref[pl.ds(seg, 1), :] + w * row
 
 
 @functools.partial(

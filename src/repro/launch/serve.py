@@ -335,6 +335,9 @@ def main():
                     help="fraction of audited queries also checked "
                          "against the BFS oracle")
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     wa = mon = None
     if args.obs:

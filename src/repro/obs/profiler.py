@@ -23,26 +23,18 @@ import jax
 def device_trace(logdir: str, enabled: bool = True):
     """Opt-in ``jax.profiler`` capture around a serving pass.
 
-    No-op when ``enabled`` is False, and degrades to a no-op (rather
-    than failing the serve) when the runtime cannot start a capture —
-    e.g. a second concurrent capture, or a backend without profiler
-    support.
+    No-op when ``enabled`` is False.  A requested capture that cannot
+    start (a second concurrent capture, a backend without profiler
+    support) raises: a run asked to trace must not pass untraced.
     """
     if not enabled:
         yield
         return
-    try:
-        jax.profiler.start_trace(logdir)
-    except Exception:        # capture unavailable: never fail the serve
-        yield
-        return
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()
 
 
 def annotate(name: str):

@@ -449,9 +449,13 @@ def test_engine_cost_model_sanity(built):
 
 
 def test_device_trace_degrades_gracefully(tmp_path):
-    # must never fail the serve, whatever the backend supports
+    # a requested capture runs; one that cannot start (here a second
+    # concurrent capture) raises instead of passing untraced; disabled
+    # is a no-op
     with obs.device_trace(str(tmp_path / "prof"), enabled=True):
-        pass
+        with pytest.raises(RuntimeError):
+            with obs.device_trace(str(tmp_path / "prof2"), enabled=True):
+                pass
     with obs.device_trace("", enabled=False):
         pass
 
