@@ -339,12 +339,16 @@ class Frontend:
                         if now >= deadline:
                             reason = "n_flush_deadline"
                             break
-                        self._cond.wait(timeout=deadline - now)
+                        with span("frontend.wait", cat="frontend",
+                                  why="deadline"):
+                            self._cond.wait(timeout=deadline - now)
                     elif self._closed:
                         return
                     else:
                         self._force = False
-                        self._cond.wait()
+                        with span("frontend.wait", cat="frontend",
+                                  why="empty"):
+                            self._cond.wait()
                 batch = self._pending[: self.max_batch]
                 del self._pending[: self.max_batch]
                 # flush lateness: how far past the oldest request's
@@ -364,7 +368,7 @@ class Frontend:
                 self._c_misses.inc()
             t_serve = self._clock()
             try:
-                self._serve(batch, reason)
+                self._serve(batch, reason, lateness)
             except BaseException as e:  # noqa: BLE001 — last-resort latch
                 # _serve latches engine errors itself; this guard means
                 # even a failure in its own bookkeeping cannot strand
@@ -379,7 +383,8 @@ class Frontend:
                 self._g_inflight.set(0)
                 self._cond.notify_all()
 
-    def _serve(self, batch: List[tuple], reason: str) -> None:
+    def _serve(self, batch: List[tuple], reason: str,
+               lateness: float) -> None:
         # budget-expired requests are dropped at the flush boundary —
         # serving them would spend engine time on answers nobody can
         # use within their SLO
@@ -400,23 +405,28 @@ class Frontend:
             if not batch:
                 return
         ctxs = [b[5] for b in batch]
+        # The trace scope makes the batch's ids ambient: every span the
+        # engine stack opens below (padder, megakernel, shard fan-out,
+        # dynamic probes) tags itself with them, and the resilient
+        # engine attributes retries/degradations to them.  (One gate
+        # check per batch: disabled serving skips the scope push — the
+        # contexts are all NULL then anyway — and the wait sum.)
+        if _TRACER.enabled:
+            sc = trace_context.scope(ctxs)
+            wait_us_sum = sum(now - b[3] for b in batch) * 1e6
+        else:
+            sc, wait_us_sum = contextlib.nullcontext(), 0.0
         try:
             # assembly inside the latch too: no input may ever kill the
-            # scheduler thread and strand the batch's futures.  The
-            # trace scope makes the batch's ids ambient: every span the
-            # engine stack opens below (padder, megakernel, shard
-            # fan-out, dynamic probes) tags itself with them, and the
-            # resilient engine attributes retries/degradations to them.
-            # (One gate check per batch: disabled serving skips the
-            # scope push — the contexts are all NULL then anyway.)
-            sc = (trace_context.scope(ctxs) if _TRACER.enabled
-                  else contextlib.nullcontext())
+            # scheduler thread and strand the batch's futures
             with sc, \
                     span("frontend.flush", cat="frontend", n=len(batch),
-                         reason=reason):
-                fault_point("frontend.queue_stall", n=len(batch))
-                us = np.array([b[0] for b in batch], dtype=np.int64)
-                rects = np.stack([b[1] for b in batch])
+                         reason=reason, wait_us_sum=wait_us_sum,
+                         late_us=lateness * 1e6):
+                with span("frontend.assemble", cat="frontend"):
+                    fault_point("frontend.queue_stall", n=len(batch))
+                    us = np.array([b[0] for b in batch], dtype=np.int64)
+                    rects = np.stack([b[1] for b in batch])
                 fault_point("frontend.flush", n=len(batch))
                 if getattr(self.engine, "supports_deadline", False):
                     dls = [b[4] - now for b in batch if b[4] is not None]
@@ -436,18 +446,20 @@ class Frontend:
         self._g_occupancy.set(len(batch) / self.max_batch)
         now = self._clock()
         tracing = _TRACER.enabled
-        for (_, _, fut, t_enq, _, ctx), a in zip(batch, ans):
-            # queue-wait exemplars join the p99 quantile back to real
-            # requests; only retained while tracing (reservoir writes
-            # stay off the disabled fast path)
-            self._h_wait.record(
-                (now - t_enq) * 1e6,
-                exemplar=ctx.trace_id if tracing else None)
-            try:
-                fut.set_result(bool(a))
-            except InvalidStateError:       # client cancelled meanwhile
-                pass
-        self._log_batch(us, rects, ans, batch, now)
+        with sc:
+            with span("frontend.resolve", cat="frontend", n=len(batch)):
+                for (_, _, fut, t_enq, _, ctx), a in zip(batch, ans):
+                    # queue-wait exemplars join the p99 quantile back to
+                    # real requests; only retained while tracing
+                    # (reservoir writes stay off the disabled fast path)
+                    self._h_wait.record(
+                        (now - t_enq) * 1e6,
+                        exemplar=ctx.trace_id if tracing else None)
+                    try:
+                        fut.set_result(bool(a))
+                    except InvalidStateError:   # client cancelled
+                        pass
+            self._log_batch(us, rects, ans, batch, now)
         if self._auditor is not None:
             self._auditor.observe(us, rects, ans,
                                   trace_ids=[c.trace_id for c in ctxs])
@@ -461,25 +473,26 @@ class Frontend:
             if not _TRACER.enabled:
                 return
             qlog = obs_querylog.QUERY_LOG
-        shard_of = getattr(self.engine, "shard_of", None)
-        shards = (shard_of(us) if shard_of is not None
-                  else np.zeros(len(us), dtype=np.int64))
-        vclass = obs_querylog.vertex_class_of(self.engine, us)
-        lats = [now - b[3] for b in batch]
-        # engine-reported serving status (resilient engines rewrite
-        # last_report per batch): healthy vs exact-host-degraded split
-        statuses, retries, attempts = "ok", 0, None
-        rep = getattr(self.engine, "last_report", None)
-        if rep is not None:
-            mask = np.asarray(rep.get("degraded", ()), dtype=bool)
-            if len(mask) == len(us):
-                statuses = np.where(mask, "degraded", "ok")
-            retries = int(rep.get("retries", 0))
-            att = rep.get("attempts")
-            if att is not None and len(att) == len(us):
-                attempts = att
-        qlog.record_batch("reach", vclass, rects, shards, lats,
-                          np.asarray(ans).astype(np.int64), us=us,
-                          statuses=statuses, retries=retries,
-                          trace_ids=[b[5].trace_id for b in batch],
-                          attempts=attempts)
+        with span("frontend.querylog", cat="frontend", n=len(batch)):
+            shard_of = getattr(self.engine, "shard_of", None)
+            shards = (shard_of(us) if shard_of is not None
+                      else np.zeros(len(us), dtype=np.int64))
+            vclass = obs_querylog.vertex_class_of(self.engine, us)
+            lats = [now - b[3] for b in batch]
+            # engine-reported serving status (resilient engines rewrite
+            # last_report per batch): healthy vs exact-host-degraded split
+            statuses, retries, attempts = "ok", 0, None
+            rep = getattr(self.engine, "last_report", None)
+            if rep is not None:
+                mask = np.asarray(rep.get("degraded", ()), dtype=bool)
+                if len(mask) == len(us):
+                    statuses = np.where(mask, "degraded", "ok")
+                retries = int(rep.get("retries", 0))
+                att = rep.get("attempts")
+                if att is not None and len(att) == len(us):
+                    attempts = att
+            qlog.record_batch("reach", vclass, rects, shards, lats,
+                              np.asarray(ans).astype(np.int64), us=us,
+                              statuses=statuses, retries=retries,
+                              trace_ids=[b[5].trace_id for b in batch],
+                              attempts=attempts)

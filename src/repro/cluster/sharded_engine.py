@@ -44,7 +44,6 @@ identical SPMD everywhere and steady state still recompiles nothing.
 
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -79,8 +78,7 @@ from ..kernels.range_query.fused import (
 )
 from ..kernels.range_query.kernel import TB
 from ..launch.mesh import make_shard_mesh
-from ..obs import REGISTRY, span
-from ..obs.tracer import TRACER as _TRACER
+from ..obs import span
 from ..resilience.faults import fault_point
 from .partition import partition_forest, shard_arenas
 
@@ -372,36 +370,27 @@ class ShardedEngine:
         out[ok] = self.partition.tree_shard[t[ok]]
         return out
 
-    def _finish_batch(self, B, Bb, kb, forced, own, hit, cnt, t0):
-        """Shared batch epilogue (fused + two-phase): stats, sync,
-        per-shard routing/hit counters, gated registry recording."""
+    def _finish_batch(self, B, Bb, kb, forced, own, hit, cnt):
+        """Shared batch epilogue (fused + two-phase): sync, then the
+        stats and per-shard routing/hit counters."""
         S = self.n_shards
-        self.stats["batches"] += 1
-        self.stats["queries"] += B
-        self.stats["tiles_scanned"] += int(np.asarray(cnt).sum())
-        self.stats["tiles_grid"] += (Bb // TB) * kb * S
-        self.stats["tiles_full_scan"] += (Bb // TB) * self.n_tiles * S
         with span("cluster.sync", cat="cluster"):
             # routing stats over the *real* lanes only (padding
             # reuses vertex 0, which routes to a real shard but
             # answers nothing)
             own_b = np.asarray(own)[:B]
             out = (np.asarray(hit) > 0) | np.asarray(forced)
-        routed = own_b >= 0
-        self.shard_queries += np.bincount(
-            own_b[routed], minlength=S).astype(np.int64)
-        self.shard_hits += np.bincount(
-            own_b[routed & out[:B]], minlength=S).astype(np.int64)
-        if _TRACER.enabled:
-            dt_us = (time.perf_counter() - t0) * 1e6
-            REGISTRY.histogram("cluster.batch_us").record(dt_us)
-            REGISTRY.gauge("cluster.n_compiles").set(self.n_compiles)
-            for s in np.nonzero(np.bincount(own_b[routed],
-                                            minlength=S))[0]:
-                REGISTRY.counter(f"cluster.shard{s}.queries").inc(
-                    int((own_b == s).sum()))
-                REGISTRY.counter(f"cluster.shard{s}.hits").inc(
-                    int((routed & out[:B] & (own_b == s)).sum()))
+        with span("cluster.tally", cat="cluster"):
+            self.stats["batches"] += 1
+            self.stats["queries"] += B
+            self.stats["tiles_scanned"] += int(np.asarray(cnt).sum())
+            self.stats["tiles_grid"] += (Bb // TB) * kb * S
+            self.stats["tiles_full_scan"] += (Bb // TB) * self.n_tiles * S
+            routed = own_b >= 0
+            self.shard_queries += np.bincount(
+                own_b[routed], minlength=S).astype(np.int64)
+            self.shard_hits += np.bincount(
+                own_b[routed & out[:B]], minlength=S).astype(np.int64)
         return out[:B]
 
     def query_batch(self, us: np.ndarray, rects: np.ndarray) -> np.ndarray:
@@ -412,24 +401,25 @@ class ShardedEngine:
         if B == 0:
             return np.zeros(0, dtype=bool)
         fault_point("cluster.query_batch", n=B)
-        t0 = time.perf_counter()
         with span("cluster.query_batch", cat="cluster", n=B):
             with span("cluster.pad_batch", cat="cluster"):
                 Bb, us_dev, rsoa_dev = self._padder.pad(us, rects)
             with span("cluster.fused", cat="cluster", batch=B):
                 while True:
                     kcap = min(self._kb_hwm, self.n_tiles)
-                    forced, own, hit, cnt, mx = self._fused_prog(kcap)(
-                        self._side, self._grid, self._qfine, self._qcoarse,
-                        self._entries, *self._routing, us_dev, rsoa_dev)
+                    with span("cluster.dispatch", cat="cluster"):
+                        forced, own, hit, cnt, mx = self._fused_prog(kcap)(
+                            self._side, self._grid, self._qfine,
+                            self._qcoarse, self._entries, *self._routing,
+                            us_dev, rsoa_dev)
                     # int(mx) blocks on the whole collective launch
-                    mxi = int(mx)
+                    with span("cluster.block", cat="cluster"):
+                        mxi = int(mx)
                     if mxi <= kcap or kcap >= self.n_tiles:
                         break
                     self._kb_hwm = min(_bucket(mxi, 1), self.n_tiles)
                     self.stats["fused_reruns"] += 1
-            return self._finish_batch(B, Bb, kcap, forced, own, hit,
-                                      cnt, t0)
+            return self._finish_batch(B, Bb, kcap, forced, own, hit, cnt)
 
     def query_batch_two_phase(self, us: np.ndarray,
                               rects: np.ndarray) -> np.ndarray:
@@ -441,7 +431,6 @@ class ShardedEngine:
         if B == 0:
             return np.zeros(0, dtype=bool)
         fault_point("cluster.query_batch", n=B)
-        t0 = time.perf_counter()
         with span("cluster.query_batch", cat="cluster", n=B):
             with span("cluster.pad_batch", cat="cluster"):
                 Bb, us_dev, rsoa_dev = self._padder.pad(us, rects)
@@ -459,8 +448,7 @@ class ShardedEngine:
                 hit = self._scan(
                     self._entries, cand[:, :, :kb], qs, qe, rsoa_dev
                 )
-            return self._finish_batch(B, Bb, kb, forced, own, hit,
-                                      cnt, t0)
+            return self._finish_batch(B, Bb, kb, forced, own, hit, cnt)
 
     def query(self, u: int, rect) -> bool:
         return bool(self.query_batch(np.array([u]), np.array([rect]))[0])

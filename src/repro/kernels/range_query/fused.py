@@ -400,6 +400,9 @@ def fused_serve_pallas(
         out_shape=[out_shape, jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         scratch_shapes=scratch,
         interpret=interpret,
+        # the custom call's instruction name, which the benchmark's trace
+        # reduction matches to find the megakernel's device time
+        name="fused_serve_pallas",
     )(*args)
     if mode != "collect":
         out = out[:, 0]

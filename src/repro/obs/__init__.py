@@ -80,7 +80,9 @@ LAYER_PREFIXES = ("engine.", "cluster.", "frontend.", "dynamic.",
 
 
 def enable() -> None:
-    """Turn on span recording and gated hot-path metric recording."""
+    """Turn on span recording and gated hot-path metric recording; each
+    span also opens a ``jax.profiler.TraceAnnotation`` of its name."""
+    tracer.bind_annotation()
     tracer.TRACER.start()
 
 

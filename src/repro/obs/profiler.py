@@ -63,7 +63,9 @@ def engine_cost_model(engine) -> dict:
     scan_bytes_per_batch:       entry-plane bytes the scan grid touches
         (TP entries x 2*dim float32 planes per tile).
     prune_bytes_per_batch:      tile-MBR pyramid bytes the prune reads
-        per query tile (fine + coarse planes).
+        (fine + coarse float32 planes of every shard): the kernel holds
+        the pyramid for the whole call, so it is read once per call —
+        once per batch, plus once per capacity re-run.
     scan_fraction:              candidate tiles / full-arena scan — the
         prune's effectiveness; 1.0 means pruning bought nothing.
     """
@@ -80,10 +82,8 @@ def engine_cost_model(engine) -> dict:
     cand = stats.get("tiles_scanned", 0) / batches
     grid = stats.get("tiles_grid", 0) / batches
     full = stats.get("tiles_full_scan", 0) / batches
-    # the prune reads every fine tile MBR + every coarse group MBR once
-    # per query tile; query tiles per batch = grid steps / K columns
     pyramid_tiles = n_tiles * n_shards * (1 + 1 / max(COARSE_GROUP, 1))
-    qtiles = (stats.get("queries", 0) / batches) / TB
+    calls = 1 + stats.get("fused_reruns", 0) / batches
     return {
         "batches": int(stats.get("batches", 0)),
         "queries_per_batch": stats.get("queries", 0) / batches,
@@ -92,6 +92,6 @@ def engine_cost_model(engine) -> dict:
         "full_scan_tiles_per_batch": full,
         "scan_fraction": cand / full if full else None,
         "scan_bytes_per_batch": grid * tile_bytes,
-        "prune_bytes_per_batch": qtiles * pyramid_tiles * planes * 4,
+        "prune_bytes_per_batch": calls * pyramid_tiles * planes * 4,
         "tile_shape": {"TB": TB, "TP": TP, "planes": planes},
     }

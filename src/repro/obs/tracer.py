@@ -7,7 +7,11 @@ shared no-op context manager, so the hot path pays ~100ns per span
 (gated <2% of the smoke bench by ``benchmarks/obs_overhead.py``).
 Enabled, each span records a Chrome trace "complete" event (``ph: "X"``)
 into a bounded in-memory buffer: name, category, thread id, start/dur in
-microseconds, and any keyword args.  The buffer is thread-safe (the
+microseconds, and any keyword args.  Once :func:`repro.obs.enable` has
+bound ``jax.profiler.TraceAnnotation`` (:func:`bind_annotation`), each
+enabled span also opens an annotation of the same name, so a profiler
+capture shows the program's spans on its host thread lines, on the
+device trace's own clock.  The buffer is thread-safe (the
 frontend scheduler thread and compaction builders trace concurrently
 with the caller) and drops-with-a-counter rather than growing without
 bound.
@@ -34,6 +38,19 @@ from . import trace_context
 # Timestamps are perf_counter_ns throughout, so spans recorded on any
 # thread share one monotonic clock and line up in the trace viewer.
 _now_ns = time.perf_counter_ns
+
+# ``jax.profiler.TraceAnnotation`` once bound; None keeps this module
+# free of the jax import until tracing is turned on.
+_annotation = None
+
+
+def bind_annotation() -> None:
+    """Bind ``jax.profiler.TraceAnnotation`` (once), so that every
+    enabled span also lands in any profiler capture."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
 
 
 class Tracer:
@@ -219,7 +236,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_name", "_cat", "_args", "_t0")
+    __slots__ = ("_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args: Optional[dict]):
         self._name = name
@@ -227,11 +244,17 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        ann = self._ann = (None if _annotation is None
+                           else _annotation(self._name))
+        if ann is not None:
+            ann.__enter__()
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = _now_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         args = self._args
         # an active per-request scope stamps its trace ids onto every
         # span recorded within it — the causal key the flight-recorder
@@ -268,11 +291,8 @@ def traced(name: Optional[str] = None, cat: str = ""):
         def wrapper(*a, **kw):
             if not TRACER.enabled:
                 return fn(*a, **kw)
-            t0 = _now_ns()
-            try:
+            with _Span(label, cat, None):
                 return fn(*a, **kw)
-            finally:
-                TRACER.record(label, cat, t0, _now_ns() - t0, None)
 
         return wrapper
 

@@ -15,7 +15,9 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.cluster import Frontend
+from repro.obs import QueryLog
 from repro.obs.metrics import Registry
 from repro.resilience import (
     DeadlineExceeded,
@@ -342,3 +344,123 @@ def test_close_drain_true_still_serves_everything():
     futs = [fe.submit(i, RECT) for i in range(5)]
     fe.close(timeout=10)                   # drain=True default
     assert all(f.result(timeout=10) is True for f in futs)
+
+
+# ----------------------------------------------------------------------
+# spans of the request path
+# ----------------------------------------------------------------------
+
+@pytest.fixture
+def traced():
+    """The global tracer on and empty for one test."""
+    obs.disable()
+    obs.reset()
+    obs.enable()
+    try:
+        yield obs.TRACER
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+def _inside(inner, outer):
+    return outer[3] <= inner[3] and \
+        inner[3] + inner[4] <= outer[3] + outer[4]
+
+
+def test_flush_span_carries_wait_sum_and_lateness(traced):
+    """``frontend.flush`` carries the sum over its batch of (flush start
+    - enqueue) and the flush's lateness, on the Frontend's own clock."""
+    clock = FakeClock()
+    eng = BlockableEngine()
+    with Frontend(eng, max_batch=8, max_delay=10.0, clock=clock,
+                  query_log=QueryLog()) as fe:
+        futs = [fe.submit(0, RECT)]
+        clock.advance(fe, 1.0)
+        futs.append(fe.submit(1, RECT))
+        clock.advance(fe, 1.0)
+        futs.append(fe.submit(2, RECT))
+        clock.advance(fe, 8.0)             # the oldest's deadline: t=10
+        assert all(f.result(timeout=10) for f in futs)
+    (flush,) = [e for e in traced.events() if e[0] == "frontend.flush"]
+    args = flush[5]
+    assert args["n"] == 3 and args["reason"] == "n_flush_deadline"
+    assert args["wait_us_sum"] == pytest.approx((10 + 9 + 8) * 1e6)
+    assert args["late_us"] == 0.0
+
+
+def test_late_flush_reports_its_lateness(traced):
+    clock = FakeClock()
+    eng = BlockableEngine(block_first=True)
+    fe = Frontend(eng, max_batch=1, max_queue=8, max_delay=10.0,
+                  clock=clock)
+    try:
+        f1 = fe.submit(0, RECT)            # flushes full, engine blocks
+        assert eng.entered.wait(timeout=10)
+        f2 = fe.submit(1, RECT)            # waits behind the inflight
+        clock.advance(fe, 100.0)
+        eng.release.set()
+        assert f1.result(timeout=10) and f2.result(timeout=10)
+    finally:
+        fe.close()
+    flushes = sorted((e for e in traced.events()
+                      if e[0] == "frontend.flush"), key=lambda e: e[3])
+    assert [e[5]["late_us"] for e in flushes] == \
+        [0.0, pytest.approx(90e6)]
+    assert flushes[1][5]["wait_us_sum"] == pytest.approx(100e6)
+
+
+def test_request_path_spans_nest_on_the_scheduler_thread(traced):
+    """``frontend.wait`` around the scheduler's waits, ``assemble``
+    inside ``flush``, then ``resolve`` and ``querylog`` after it, all on
+    the scheduler's thread."""
+    clock = FakeClock()
+    eng = BlockableEngine()
+    qlog = QueryLog()
+    fe = Frontend(eng, max_batch=4, max_delay=10.0, clock=clock,
+                  query_log=qlog)
+    try:
+        futs = [fe.submit(i, RECT) for i in range(2)]
+
+        def waited_for_deadline():
+            clock.advance(fe, 0.0)         # wakes a waiting scheduler
+            return any(e[0] == "frontend.wait"
+                       and e[5]["why"] == "deadline"
+                       for e in traced.events())
+
+        _await(waited_for_deadline, what="a deadline wait")
+        clock.advance(fe, 10.0)
+        assert all(f.result(timeout=10) for f in futs)
+    finally:
+        fe.close(timeout=10)
+    ev = [e for e in traced.events() if e[0].startswith("frontend.")]
+    assert {e[2] for e in ev} == {fe._thread.ident}
+    by = {}
+    for e in ev:
+        by.setdefault(e[0], []).append(e)
+    assert {e[5]["why"] for e in by["frontend.wait"]} == {"empty",
+                                                          "deadline"}
+    (flush,), (asm,), (res,), (ql,) = (
+        by[f"frontend.{n}"] for n in ("flush", "assemble", "resolve",
+                                      "querylog"))
+    assert _inside(asm, flush)
+    assert flush[3] + flush[4] <= res[3]
+    assert res[3] + res[4] <= ql[3]
+    assert res[5]["n"] == ql[5]["n"] == 2
+    for w in by["frontend.wait"]:
+        assert w[3] + w[4] <= flush[3] or w[3] >= ql[3] + ql[4]
+    assert qlog.total == 2
+
+
+def test_querylog_span_only_when_the_log_runs(traced):
+    """Without an explicit log and with the tracer off, nothing is
+    logged; with the tracer on, the global log runs under its span."""
+    eng = BlockableEngine()
+    with Frontend(eng, max_batch=2, max_delay=1e-3) as fe:
+        fe.submit_many([0, 1], np.tile(RECT, (2, 1)))
+    assert [e[0] for e in traced.events()].count("frontend.querylog") == 1
+    obs.disable()
+    traced.clear()
+    with Frontend(eng, max_batch=2, max_delay=1e-3) as fe:
+        fe.submit_many([0, 1], np.tile(RECT, (2, 1)))
+    assert len(traced) == 0

@@ -448,6 +448,95 @@ def test_engine_cost_model_sanity(built):
     assert cm["tile_shape"]["planes"] == 4
 
 
+def test_engine_cost_model_reads_the_pyramid_once_per_call(built):
+    """The pyramid is read once per kernel call, whatever the batch
+    size: 16 bytes a fine tile (4 float32 rows) and 16 a coarse group
+    of 8, so 18 bytes a tile."""
+    from repro.core import QueryEngine
+
+    _, idx, _, us, rects = built
+    eng = QueryEngine(idx)
+    eng.query_batch(us, rects)
+    eng.query_batch(us[:8], rects[:8])
+    calls = 1 + eng.stats["fused_reruns"] / eng.stats["batches"]
+    cm = obs.engine_cost_model(eng)
+    assert cm["prune_bytes_per_batch"] == pytest.approx(
+        18 * eng.n_tiles * calls)
+    for _ in range(4):
+        eng.query_batch(us[:8], rects[:8])
+    calls = 1 + eng.stats["fused_reruns"] / eng.stats["batches"]
+    assert obs.engine_cost_model(eng)["prune_bytes_per_batch"] == \
+        pytest.approx(18 * eng.n_tiles * calls)
+
+
+def test_enabled_spans_land_in_a_profiler_capture(tmp_path):
+    """An enabled span is also a ``jax.profiler`` annotation on a host
+    plane of the capture, on the profiler's clock; a disabled one is
+    not."""
+    import jax
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("test.enabled_span", cat="t"):
+            time.sleep(0.001)
+        obs.disable()
+        with obs.span("test.disabled_span", cat="t"):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = list(tmp_path.glob("**/*.xplane.pb"))
+    names = {e.name: e.duration_ns for plane in
+             ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert names["test.enabled_span"] >= 1e6
+    assert "test.disabled_span" not in names
+    assert [e[0] for e in obs.TRACER.events()] == ["test.enabled_span"]
+
+
+def test_sharded_engine_spans_nest(built):
+    """``cluster.dispatch`` and ``cluster.block`` lie inside
+    ``cluster.fused``; ``cluster.sync`` then ``cluster.tally`` follow it
+    inside ``cluster.query_batch``.  Tracing writes no ``cluster.*``
+    registry entries: the spans and the engine's own counters hold
+    that."""
+    from repro.cluster import ShardedEngine
+
+    _, idx, _, us, rects = built
+    eng = ShardedEngine(idx, n_shards=1)
+    eng.query_batch(us, rects)
+    routed = int(eng.shard_queries.sum())
+    obs.enable()
+    eng.query_batch(us, rects)
+    obs.disable()
+    by = {}
+    for e in obs.TRACER.events():
+        by.setdefault(e[0], []).append(e)
+
+    def inside(inner, outer):
+        return outer[3] <= inner[3] and \
+            inner[3] + inner[4] <= outer[3] + outer[4]
+
+    (qb,), (fused,), (sync,), (tally,) = (
+        by[f"cluster.{n}"] for n in ("query_batch", "fused", "sync",
+                                     "tally"))
+    assert by["cluster.dispatch"] and by["cluster.block"]
+    for e in by["cluster.dispatch"] + by["cluster.block"]:
+        assert inside(e, fused)
+    assert by["cluster.dispatch"][0][3] + by["cluster.dispatch"][0][4] \
+        <= by["cluster.block"][0][3]
+    assert fused[3] + fused[4] <= sync[3]
+    assert sync[3] + sync[4] <= tally[3]
+    for e in (fused, sync, tally):
+        assert inside(e, qb)
+    snap = obs.REGISTRY.snapshot()
+    assert not [k for kind in snap.values() if isinstance(kind, dict)
+                for k in kind if k.startswith("cluster.")]
+    assert int(eng.shard_queries.sum()) == 2 * routed > 0
+
+
 def test_device_trace_degrades_gracefully(tmp_path):
     # a requested capture runs; one that cannot start (here a second
     # concurrent capture) raises instead of passing untraced; disabled

@@ -12,6 +12,10 @@ only one process may load the TPU library, and every test worker
 imports this file.
 """
 
+import os
+import re
+import sys
+
 import numpy as np
 import pytest
 
@@ -19,6 +23,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from bench.trace_reduce import MEGAKERNEL  # noqa: E402
 
 # Gowalla x50 (get_dataset("gowalla", scale=50.0), method 2dreach):
 # 3.1M vertices, 2,835,341 leaf entries -> 22,152 leaf tiles
@@ -66,6 +74,15 @@ def _compile(fn, *args):
     return compiled
 
 
+def _megakernel_calls(hlo: str) -> list:
+    """Instruction names of the TPU custom calls that the benchmark's
+    trace reduction takes for the megakernel: its device time is found
+    by this name, so a refactor must not lose it."""
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", hlo)
+    return [c for c in calls if MEGAKERNEL in c]
+
+
 @pytest.mark.parametrize("mode", ["reach", "count", "collect"])
 def test_fused_serve_compiles(one_chip, mode):
     from repro.kernels.range_query.fused import fused_serve_pallas
@@ -78,6 +95,7 @@ def test_fused_serve_compiles(one_chip, mode):
     compiled = _compile(
         lambda *a: fused_serve_pallas(*a, mode=mode, kcap=KCAP,
                                       nt=N_TILES), *args)
+    assert len(_megakernel_calls(compiled.as_text())) == 1
     mem = compiled.memory_analysis()
     # the arena stays in HBM: arguments dominate, the program's own
     # temporaries are the transposed query rows and repeated coarse plane
@@ -174,6 +192,7 @@ def test_sharded_fused_program_compiles_on_four_chips(topo):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+    assert len(_megakernel_calls(hlo)) == 1
 
 
 def test_engine_programs_take_the_index_as_arguments():
