@@ -13,12 +13,14 @@ device routes the replicated batch, masks it to the queries whose trees
 live on its shards, runs the quantized-plane fused prune+scan per local
 shard, and the per-query hits ``psum``-OR-reduce across the mesh in the
 same trace that ``pmax``-es the candidate max — no prune→host→scan
-round trip, one dispatch per batch per capacity bucket (the capacity is
-a monotone high-water mark: an overflowing batch ratchets and re-runs
-once; steady state runs exactly once).  The pre-fusion two-phase
-structure (separate route+prune and scan ``shard_map`` jits with a host
-bucket step between them) is retained as ``query_batch_two_phase`` —
-the reference the fused program is bit-compared against:
+round trip, one dispatch and one device-to-host copy (the program's
+outputs packed into one vector) per batch per capacity bucket (the
+capacity is a monotone high-water mark: an overflowing batch ratchets
+and re-runs once; steady state runs exactly once).  The pre-fusion
+two-phase structure (separate route+prune and scan ``shard_map`` jits
+with a host bucket step between them) is retained as
+``query_batch_two_phase`` — the reference the fused program is
+bit-compared against:
 
 1. **route + prune** — every device evaluates the fused pointer lookup
    for the whole (replicated) batch, masks it down to the queries whose
@@ -105,7 +107,20 @@ def fused_program(mesh, *, shards_per_dev: int, nt: int, dim: int,
     entries, tree_shard, tree_qs, tree_qe, us, rsoa)``, the arena stacks
     sharded over the mesh's ``data`` axis and the rest replicated — so
     the program can be lowered from shapes alone (the TPU compile
-    rehearsal does, on a described mesh)."""
+    rehearsal does, on a described mesh).
+
+    The program returns ONE replicated ``int32`` vector, so that a batch
+    costs one device-to-host copy.  For a padded batch of ``Bb`` lanes
+    over ``S`` shards (``nb = Bb // TB`` query tiles) it holds, in order
+    (:func:`unpack` reads it back):
+
+    - ``mx`` (1): the candidate-tile max over every shard and query
+      tile, the capacity check;
+    - ``cnt`` (``S * nb``): the true candidate-tile counts, shard major,
+      ``all_gather``-ed across the mesh;
+    - the answer bit per lane (``Bb``): ``(psum(hit) > 0) | forced``;
+    - ``own`` per lane (``Bb``): the owning shard, -1 where no tree
+      answers."""
     L = shards_per_dev
 
     def fused(side, grid, qfine, qcoarse, entries, tshard, tqs, tqe, us,
@@ -141,14 +156,27 @@ def fused_program(mesh, *, shards_per_dev: int, nt: int, dim: int,
         mx = jax.lax.pmax(cnt.max(), _AXIS)
         # OR-reduce across shards: hits are 0/1 and each query's
         # tree lives on exactly one shard, so a sum is an OR
-        return forced, own, jax.lax.psum(hit, _AXIS), cnt, mx
+        ans = (jax.lax.psum(hit, _AXIS) > 0) | forced
+        cnt_all = jax.lax.all_gather(cnt.reshape(-1), _AXIS, tiled=True)
+        return jnp.concatenate(
+            [mx[None], cnt_all, ans.astype(jnp.int32), own])
 
     return jax.jit(shard_map(
         fused, mesh,
         in_specs=(P(), P(), P(_AXIS), P(_AXIS), P(_AXIS), P(), P(), P(),
                   P(), P()),
-        out_specs=(P(), P(), P(), P(_AXIS), P()),
+        out_specs=P(),
     ))
+
+
+def unpack(packed: np.ndarray, Bb: int):
+    """``(cnt, ans, own)`` from :func:`fused_program`'s packed output,
+    fetched to the host, for a batch of ``Bb`` lanes: ``cnt`` the flat
+    per-shard counts, ``ans`` a bool per lane, ``own`` the owning shard
+    per lane (``mx`` is ``packed[0]``)."""
+    nc = len(packed) - 1 - 2 * Bb
+    return (packed[1: 1 + nc], packed[1 + nc: 1 + nc + Bb] != 0,
+            packed[1 + nc + Bb:])
 
 
 class ShardedEngine:
@@ -233,6 +261,9 @@ class ShardedEngine:
             "adopted": int(getattr(index.forest, "device", None) is not None),
             "tiles_scanned": 0, "tiles_grid": 0, "tiles_full_scan": 0,
             "fused_reruns": 0,
+            # device-to-host transfers of the fused path: one a batch,
+            # one more a capacity re-run
+            "host_fetches": 0,
         }
         self.shard_queries = np.zeros(n_shards, dtype=np.int64)
         # per-shard hit counters ride next to the query routing counts:
@@ -370,32 +401,32 @@ class ShardedEngine:
         out[ok] = self.partition.tree_shard[t[ok]]
         return out
 
-    def _finish_batch(self, B, Bb, kb, forced, own, hit, cnt):
-        """Shared batch epilogue (fused + two-phase): sync, then the
-        stats and per-shard routing/hit counters."""
+    def _tally(self, B, Bb, kb, own, out, cnt):
+        """Shared batch epilogue (fused + two-phase) on host arrays —
+        no device transfer: the stats and the per-shard routing/hit
+        counters.  Returns the answers of the ``B`` real lanes."""
         S = self.n_shards
-        with span("cluster.sync", cat="cluster"):
+        with span("cluster.tally", cat="cluster"):
             # routing stats over the *real* lanes only (padding
             # reuses vertex 0, which routes to a real shard but
             # answers nothing)
-            own_b = np.asarray(own)[:B]
-            out = (np.asarray(hit) > 0) | np.asarray(forced)
-        with span("cluster.tally", cat="cluster"):
+            own_b, out_b = own[:B], out[:B]
             self.stats["batches"] += 1
             self.stats["queries"] += B
-            self.stats["tiles_scanned"] += int(np.asarray(cnt).sum())
+            self.stats["tiles_scanned"] += int(cnt.sum())
             self.stats["tiles_grid"] += (Bb // TB) * kb * S
             self.stats["tiles_full_scan"] += (Bb // TB) * self.n_tiles * S
             routed = own_b >= 0
             self.shard_queries += np.bincount(
                 own_b[routed], minlength=S).astype(np.int64)
             self.shard_hits += np.bincount(
-                own_b[routed & out[:B]], minlength=S).astype(np.int64)
-        return out[:B]
+                own_b[routed & out_b], minlength=S).astype(np.int64)
+        return out_b
 
     def query_batch(self, us: np.ndarray, rects: np.ndarray) -> np.ndarray:
         """Batched RangeReach, bit-identical to the host path — one
-        fused collective dispatch per batch (per capacity bucket)."""
+        fused collective dispatch and one device-to-host copy per batch
+        (per capacity bucket)."""
         us = np.asarray(us, dtype=np.int64)
         B = len(us)
         if B == 0:
@@ -408,18 +439,25 @@ class ShardedEngine:
                 while True:
                     kcap = min(self._kb_hwm, self.n_tiles)
                     with span("cluster.dispatch", cat="cluster"):
-                        forced, own, hit, cnt, mx = self._fused_prog(kcap)(
+                        packed = self._fused_prog(kcap)(
                             self._side, self._grid, self._qfine,
                             self._qcoarse, self._entries, *self._routing,
                             us_dev, rsoa_dev)
-                    # int(mx) blocks on the whole collective launch
+                    # the wait on the whole collective launch and the
+                    # batch's one device-to-host copy, queued behind the
+                    # program so that it starts as soon as the program ends
                     with span("cluster.block", cat="cluster"):
-                        mxi = int(mx)
+                        packed.copy_to_host_async()
+                        host = np.asarray(packed)
+                    self.stats["host_fetches"] += 1
+                    mxi = int(host[0])      # the packed layout's mx
                     if mxi <= kcap or kcap >= self.n_tiles:
                         break
                     self._kb_hwm = min(_bucket(mxi, 1), self.n_tiles)
                     self.stats["fused_reruns"] += 1
-            return self._finish_batch(B, Bb, kcap, forced, own, hit, cnt)
+            with span("cluster.sync", cat="cluster"):
+                cnt, out, own = unpack(host, Bb)
+            return self._tally(B, Bb, kcap, own, out, cnt)
 
     def query_batch_two_phase(self, us: np.ndarray,
                               rects: np.ndarray) -> np.ndarray:
@@ -448,7 +486,11 @@ class ShardedEngine:
                 hit = self._scan(
                     self._entries, cand[:, :, :kb], qs, qe, rsoa_dev
                 )
-            return self._finish_batch(B, Bb, kb, forced, own, hit, cnt)
+            with span("cluster.sync", cat="cluster"):
+                own = np.asarray(own)
+                out = (np.asarray(hit) > 0) | np.asarray(forced)
+                cnt = np.asarray(cnt)
+            return self._tally(B, Bb, kb, own, out, cnt)
 
     def query(self, u: int, rect) -> bool:
         return bool(self.query_batch(np.array([u]), np.array([rect]))[0])

@@ -184,6 +184,55 @@ def test_sharded_no_steady_state_recompiles(graph, indexes):
     assert eng.stats["uploads"] == 1
 
 
+@pytest.mark.parametrize("n_shards", [1, 8])
+def test_sharded_one_host_fetch_per_batch(n_shards):
+    """The fused path copies one packed vector to the host per batch,
+    and one more per capacity re-run; what it unpacks equals the
+    two-phase reference's own copies: answers, candidate-tile counts and
+    the per-shard counters."""
+    # a chain of 20 users, each checking in at 150 of 3,000 venues: user
+    # 0 reaches every venue, so its tree spans many leaf tiles
+    rng = np.random.default_rng(0)
+    nu, nv = 20, 3000
+    edges = [(i, i + 1) for i in range(nu - 1)] + [
+        (i, nu + v) for i in range(nu)
+        for v in rng.choice(nv, 150, replace=False)]
+    coords = np.r_[np.zeros((nu, 2)), rng.random((nv, 2))]
+    g = make_graph(nu + nv, np.array(edges, np.int64),
+                   coords.astype(np.float32), np.arange(nu + nv) >= nu)
+    idx = build_2dreach(g, variant="base")
+    fused = ShardedEngine(idx, n_shards=n_shards)
+    ref = ShardedEngine(idx, n_shards=n_shards)
+
+    def batch(seed, n, half):
+        r = np.random.default_rng(seed)
+        c = r.random((n, 2))
+        return r.integers(0, nu, n), np.c_[c - half, c + half].astype(
+            np.float32)
+
+    narrow = [batch(s, n, 0.01) for s, n in [(1, 100), (2, 128), (3, 7),
+                                             (4, 64)]]
+    # the whole extent meets more candidate tiles than the narrow
+    # batches ratcheted the capacity to: that batch re-runs once
+    us, _ = batch(5, 64, 0.01)
+    wide = (us, np.tile(np.float32([0, 0, 1, 1]), (64, 1)))
+    reruns = []
+    for us, rects in narrow + narrow + [wide]:
+        before = fused.stats["fused_reruns"]
+        got = fused.query_batch(us, rects)
+        reruns.append(fused.stats["fused_reruns"] - before)
+        assert (got == ref.query_batch_two_phase(us, rects)).all()
+        assert (got == idx.query_batch(us, rects)).all()
+        assert fused.stats["host_fetches"] == \
+            fused.stats["batches"] + fused.stats["fused_reruns"]
+    assert reruns[len(narrow):] == [0] * len(narrow) + [1]
+    assert fused.stats["tiles_scanned"] == ref.stats["tiles_scanned"]
+    assert (fused.shard_queries == ref.shard_queries).all()
+    assert (fused.shard_hits == ref.shard_hits).all()
+    assert fused.shard_hits.sum() > 0
+    assert ref.stats["host_fetches"] == 0
+
+
 def test_sharded_engine_for_memoised_and_strict(graph, indexes):
     idx = indexes["base"]
     assert sharded_engine_for(idx) is sharded_engine_for(idx)

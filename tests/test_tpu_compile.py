@@ -163,10 +163,12 @@ def test_sharded_fused_program_compiles_on_four_chips(topo):
     """The 4-device collective serving program (one ``shard_map`` with
     the fused megakernel per shard and a psum/pmax reduce) on a mesh of
     the described chips, the giant-tree worst case: every shard padded
-    to the whole arena."""
+    to the whole arena.  It returns one replicated vector, so that a
+    batch costs one device-to-host copy."""
     from repro.cluster.sharded_engine import fused_program
     from repro.core.engine import PointerSide
     from repro.kernels.range_query.fused import QuantGrid
+    from repro.kernels.range_query.kernel import TB
 
     mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
     shard = NamedSharding(mesh, P("data"))
@@ -189,6 +191,12 @@ def test_sharded_fused_program_compiles_on_four_chips(topo):
         *(_spec((n_trees,), jnp.int32, rep) for _ in range(3)),
         _spec((B,), jnp.int32, rep), _spec((4, B), jnp.float32, rep),
     ).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    (out_sharding,) = jax.tree.leaves(compiled.output_shardings)
+    # mx, 4 shards' candidate counts per query tile, answer and owner
+    # per lane
+    assert out.dtype == jnp.int32 and out.shape == (1 + 4 * (B // TB) + 2 * B,)
+    assert out_sharding.is_fully_replicated
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
