@@ -122,23 +122,101 @@ def test_generator_has_gowallas_shape():
         cfg["venues"]
 
 
-def test_reference_agrees_with_core_oracle():
+# Yelp at arXiv:2602.05928's Table-2 counts: mutual friendships make the
+# SCCs the friend graph's components, one giant and about a million
+# isolated users
+YELP = {"users": 1987693, "venues": 150310, "edges": 21357271,
+        "social_avg_deg": 0.58, "reciprocity": 1.0, "zipf_users": 1.3,
+        "zipf_venues": 1.2, "n_cities": 12, "city_sigma": 3.0,
+        "graph_seed": 14}
+SHAPES = {"gowalla": lambda: small_cfg(3000), "yelp": lambda: cut(YELP, 3000)}
+
+
+def queries_of_every_kind(g, rng):
+    """Vertices of every out-degree (venues and users with no edge among
+    them) and random ones, with rects of 1-20% of the extent, some on a
+    venue and some inverted."""
+    deg = lbsn.out_degree(g)
+    perm = rng.permutation(g.n_nodes)
+    _, first = np.unique(deg[perm], return_index=True)
+    us = np.concatenate([perm[first], rng.integers(0, g.n_nodes, 200)])
+    ext = lbsn.spatial_extent(g)
+    rects = np.concatenate([
+        traffic.region_for_extent(ext, r, len(us), rng)
+        for r in (0.01, 0.02, 0.05, 0.10, 0.20)])
+    us = np.tile(us, 5)
+    venues = np.nonzero(g.spatial_mask)[0]
+    on = rng.choice(venues, 40)
+    rects[:40] = np.concatenate([g.coords[on], g.coords[on]], axis=1)
+    rects[40:80] = rects[40:80][:, [2, 1, 0, 3]]     # xmin > xmax
+    rects[80:120] = rects[80:120][:, [0, 3, 2, 1]]   # ymin > ymax
+    return us, rects
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_reference_agrees_with_core_oracle(shape):
     from repro.core.graph import make_graph
     from repro.core.oracle import rangereach_oracle_batch
 
-    g = lbsn.generate(small_cfg(3000), 11)
-    rng = np.random.default_rng(0)
-    us = np.concatenate([rng.integers(0, g.n_nodes, 150),
-                         traffic.sample_vertices_by_degree(
-                             lbsn.out_degree(g), (100, 149), 50, rng)])
-    rects = traffic.region_for_extent(lbsn.spatial_extent(g), 0.02,
-                                      len(us), rng)
+    g = lbsn.generate(SHAPES[shape](), 11)
+    us, rects = queries_of_every_kind(g, np.random.default_rng(0))
     want = rangereach_oracle_batch(
         make_graph(g.n_nodes, g.edges, g.coords, g.spatial_mask), us, rects)
-    got = reference.Reference(g.n_nodes, g.edges, g.coords,
-                              g.spatial_mask).answers(us, rects)
+    ref = reference.Reference(g.n_nodes, g.edges, g.coords, g.spatial_mask)
+    got = ref.answers(us, rects)
     np.testing.assert_array_equal(got, want)
     assert 0 < want.sum() < len(want)
+    if shape == "yelp":
+        assert len(np.unique(ref.comp[us])) > 50    # many components
+
+
+def scipy_answers(g, us, rects, precision):
+    """The reference as it stood before it searched on its own arrays:
+    scipy's ``breadth_first_order`` per queried component, then the
+    grid over that component's venues."""
+    from scipy.sparse import csr_matrix
+    from scipy.sparse.csgraph import breadth_first_order, connected_components
+
+    n = g.n_nodes
+    adj = csr_matrix((np.ones(len(g.edges), bool),
+                      (g.edges[:, 0], g.edges[:, 1])), shape=(n, n))
+    _, comp = connected_components(adj, directed=True, connection="strong")
+    rects = reference._round(rects, precision)
+    out = np.zeros(len(us), dtype=bool)
+    qc = comp[us]
+    for c in np.unique(qc):
+        sel = np.nonzero(qc == c)[0]
+        seen = breadth_first_order(adj, int(us[sel[0]]), directed=True,
+                                   return_predecessors=False)
+        venues = seen[g.spatial_mask[seen]]
+        out[sel] = reference.any_point_in_rects(
+            reference._round(g.coords[venues], precision), rects[sel])
+    return out
+
+
+# (DIRECT_PAIRS, DENSE_SHARE): every component on the grid and every
+# frontier gathered; each chosen by size; every component compared pair by
+# pair and every frontier expanded over all edges
+PATHS = {"grid-gathered": (-1, 0), "mixed": (1000, 8),
+         "pairs-dense": (1 << 62, 1 << 62)}
+
+
+@pytest.mark.parametrize("paths", sorted(PATHS))
+@pytest.mark.parametrize("precision", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_reference_matches_per_component_scipy_path(shape, precision,
+                                                    paths, monkeypatch):
+    pairs, dense = PATHS[paths]
+    monkeypatch.setattr(reference, "DIRECT_PAIRS", pairs)
+    monkeypatch.setattr(reference, "DENSE_SHARE", dense)
+    g = lbsn.generate(SHAPES[shape](), 12)
+    us, rects = queries_of_every_kind(g, np.random.default_rng(1))
+    ref = reference.Reference(g.n_nodes, g.edges, g.coords, g.spatial_mask)
+    got = ref.answers(us, rects, precision=precision)
+    np.testing.assert_array_equal(got, scipy_answers(g, us, rects,
+                                                     precision))
+    assert not ref.seen.any()          # every mark reset after its search
+    assert 0 < got.sum() < len(got)
 
 
 def test_any_point_in_rects_matches_brute_force():
