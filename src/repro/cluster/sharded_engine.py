@@ -264,6 +264,9 @@ class ShardedEngine:
             # device-to-host transfers of the fused path: one a batch,
             # one more a capacity re-run
             "host_fetches": 0,
+            # distinct trees the real queries of each batch route to,
+            # summed over batches
+            "trees_routed": 0,
         }
         self.shard_queries = np.zeros(n_shards, dtype=np.int64)
         # per-shard hit counters ride next to the query routing counts:
@@ -272,9 +275,11 @@ class ShardedEngine:
         # result pressure)
         self.shard_hits = np.zeros(n_shards, dtype=np.int64)
         # host-side mirrors for query-log classification/routing: the
-        # structured log records (vertex class, shard) per served query
+        # structured log records (vertex class, shard) per served query,
+        # and each batch counts the trees it routes to; every vertex's
+        # tree (-1: excluded / no tree) is looked up once, here
         self._excluded_host = index.excluded
-        self._lookup_tree_host = index.lookup_tree
+        self._tree_host = np.asarray(index.lookup_tree(np.arange(index.n)))
         # candidate-capacity high-water mark: K only ever ratchets up, so
         # a smaller batch never traces a new K shape and lifetime scan
         # retraces are bounded by log2(n_tiles) per batch bucket.  A
@@ -395,17 +400,17 @@ class ShardedEngine:
     def shard_of(self, us: np.ndarray) -> np.ndarray:
         """Host-side vertex -> owning shard (-1: excluded / no tree) —
         the routing key the structured query log records."""
-        t = np.asarray(self._lookup_tree_host(np.asarray(us, np.int64)))
+        t = self._tree_host[np.asarray(us, np.int64)]
         out = np.full(len(t), -1, dtype=np.int64)
         ok = t >= 0
         out[ok] = self.partition.tree_shard[t[ok]]
         return out
 
-    def _tally(self, B, Bb, kb, own, out, cnt):
+    def _tally(self, us, Bb, kb, own, out, cnt):
         """Shared batch epilogue (fused + two-phase) on host arrays —
         no device transfer: the stats and the per-shard routing/hit
-        counters.  Returns the answers of the ``B`` real lanes."""
-        S = self.n_shards
+        counters.  Returns the answers of the real lanes (``us``)."""
+        S, B = self.n_shards, len(us)
         with span("cluster.tally", cat="cluster"):
             # routing stats over the *real* lanes only (padding
             # reuses vertex 0, which routes to a real shard but
@@ -417,6 +422,8 @@ class ShardedEngine:
             self.stats["tiles_grid"] += (Bb // TB) * kb * S
             self.stats["tiles_full_scan"] += (Bb // TB) * self.n_tiles * S
             routed = own_b >= 0
+            self.stats["trees_routed"] += len(np.unique(
+                self._tree_host[us[routed]]))
             self.shard_queries += np.bincount(
                 own_b[routed], minlength=S).astype(np.int64)
             self.shard_hits += np.bincount(
@@ -457,7 +464,7 @@ class ShardedEngine:
                     self.stats["fused_reruns"] += 1
             with span("cluster.sync", cat="cluster"):
                 cnt, out, own = unpack(host, Bb)
-            return self._tally(B, Bb, kcap, own, out, cnt)
+            return self._tally(us, Bb, kcap, own, out, cnt)
 
     def query_batch_two_phase(self, us: np.ndarray,
                               rects: np.ndarray) -> np.ndarray:
@@ -490,7 +497,7 @@ class ShardedEngine:
                 own = np.asarray(own)
                 out = (np.asarray(hit) > 0) | np.asarray(forced)
                 cnt = np.asarray(cnt)
-            return self._tally(B, Bb, kb, own, out, cnt)
+            return self._tally(us, Bb, kb, own, out, cnt)
 
     def query(self, u: int, rect) -> bool:
         return bool(self.query_batch(np.array([u]), np.array([rect]))[0])

@@ -21,11 +21,14 @@ module makes the device path agree:
 * **The megakernel** (:func:`fused_serve_pallas`): ONE ``pallas_call``
   over grid ``(B // TB,)``.  Each step holds its query tile's rects
   (quantized + exact), the whole quantized pyramid as full-array VMEM
-  blocks, and the entry arena left in HBM/ANY.  The pyramid is not
-  small: its 4 coordinate rows pad to a full (16, 128) int16 / (8, 128)
-  int32 tile, so the fine plane and the coarse plane (repeated to fine
-  width) take ~0.7 MB each per buffer at Gowalla x50 (22,272 tile
-  lanes), ~0.25 MB each at a million venues.  In-kernel it (1)
+  blocks (one buffer each: a full-array block at a constant index is a
+  trivial window), and the entry arena left in HBM/ANY.  The pyramid is
+  not small: its 4 coordinate rows pad to a full (16, 128) int16 /
+  (8, 128) int32 tile, so the fine plane and the coarse plane (repeated
+  to fine width) take ~0.7 MB each at Gowalla x50 (22,272 tile lanes)
+  and ~2.7 MB each at Yelp's 84,352, and each (TB, NTp) value of the
+  dense prune as much as one plane; the scoped-VMEM limit is reckoned
+  from them (:func:`megakernel_vmem_bytes`).  In-kernel it (1)
   evaluates the hierarchical coarse∧fine prune, (2) counts the
   surviving leaf tiles, and (3) walks them in ascending order — slot
   k+1's tile is the smallest active lane above slot k's (a masked lane
@@ -231,6 +234,61 @@ def compact_ascending(mask: jax.Array, nt: int
 # The megakernel (one pallas_call: prune + compact + double-buffered scan)
 # --------------------------------------------------------------------------
 
+# (TB, NTp) int32 values (a (1, NTp) row pads to as many sublanes) that
+# the dense prune and the walk hold in VMEM at once beside the pyramid:
+# the widened fine plane, the coarse plane, the lane ids, the slice test,
+# the fine and coarse box tests and their partial results, the reduced
+# row and the walk's masked row.  Compiled for a v5e, the kernel asks
+# for 319-327 bytes a lane in all at 21K-169K lanes, under the 352 that
+# this reckons (64 for the planes, 32 for each value)
+_LIVE_ROWS = 9
+# the compiler's own scoped-VMEM limit on a v5e: wherever the reckoning
+# fits it no limit is passed, so the kernel compiles as it always has
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def _padded_bytes(rows: int, lanes: int, itemsize: int) -> int:
+    """VMEM of a (rows, lanes) array in (8 * 4 / itemsize, 128) tiles."""
+    sub = 8 * 4 // itemsize
+    return (-(-rows // sub) * sub) * (-(-lanes // 128) * 128) * itemsize
+
+
+def megakernel_vmem_bytes(ntp: int, *, dim: int = 2, tb: int = TB,
+                          tp: int = TP, mode: str = "reach",
+                          kcap: int = 1) -> int:
+    """VMEM one grid step of the megakernel holds over a pyramid of
+    ``ntp`` lanes, reckoned as the chip tiles it: the two pyramid planes
+    (one buffer each), the prune's and the walk's ``_LIVE_ROWS`` values,
+    and the double-buffered per-query-tile blocks and leaf-tile DMA
+    buffers.  It grows with the arena only through the (·, NTp) terms."""
+    two = 2 * dim
+    planes = _padded_bytes(two, ntp, 2) + _padded_bytes(two, ntp, 4)
+    live = _LIVE_ROWS * _padded_bytes(tb, ntp, 4)
+    collect = mode == "collect"
+    out_lanes = kcap * tp if collect else 1
+    blocks = 2 * (_padded_bytes(tb, 2 * two, 4) + _padded_bytes(tb, two, 4)
+                  + _padded_bytes(tb, 2, 4) + _padded_bytes(tb, out_lanes, 4)
+                  + _padded_bytes(tb, 1, 4))
+    tiles = 2 * (_padded_bytes(two, tp, 4)
+                 + (_padded_bytes(1, tp, 4) if collect else 0))
+    return planes + live + blocks + tiles
+
+
+def vmem_limit(need: int) -> int | None:
+    """The scoped-VMEM limit for a kernel that needs ``need`` bytes:
+    ``None`` (the compiler's default) where that holds it, else ``need``
+    itself; refused at once where the chip's VMEM cannot hold it (known
+    on a TPU backend only)."""
+    try:
+        cap = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:      # not a TPU backend: interpret mode, or a
+        cap = None          # compile for a described topology
+    if cap is not None and need > cap:
+        raise ValueError(f"the megakernel needs {need} bytes of VMEM, "
+                         f"the chip holds {cap}")
+    return need if need > _SCOPED_VMEM_DEFAULT else None
+
+
 def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
                   mode: str, kcap: int, dim: int, tp: int):
     if mode == "collect":
@@ -399,6 +457,9 @@ def fused_serve_pallas(
         out_specs=[out_spec, pl.BlockSpec((tb, 1), lambda i: (i, 0))],
         out_shape=[out_shape, jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(
+            megakernel_vmem_bytes(ntp, dim=dim, tb=tb, tp=tp, mode=mode,
+                                  kcap=kcap))),
         interpret=interpret,
         # the custom call's instruction name, which the benchmark's trace
         # reduction matches to find the megakernel's device time

@@ -7,6 +7,9 @@ so the 8-shard layout is exercised even single-device (CI additionally
 runs this file under XLA_FLAGS=--xla_force_host_platform_device_count=8
 for a real 1-shard-per-device mesh)."""
 
+import json
+import os
+import sys
 import threading
 import time
 
@@ -416,3 +419,109 @@ def test_dynamic_sharded_base_across_compactions():
     vu, vr = workload(gm, 64, extent_ratio=0.05, seed=999)
     assert (dyn.query_batch(vu, vr)
             == rangereach_oracle_batch(gm, vu, vr)).all()
+
+
+# ------------------------------------------- many components, 2dreach-pointer
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+
+
+@pytest.fixture(scope="module")
+def yelp_pointer():
+    """The benchmark's Yelp deployment (``bench/configs/yelp-pointer.json``
+    through ``bench/lbsn.py``) cut to ~30K vertices: one giant friend SCC
+    beside users with no friends, each with a small tree of its own,
+    plus 64 users with no edge at all, which have no tree."""
+    sys.path.insert(0, ROOT)
+    from bench import lbsn
+
+    with open(os.path.join(ROOT, "bench", "configs", "yelp-pointer.json")) as f:
+        cfg = json.load(f)
+    g = lbsn.generate(lbsn.scaled(cfg, 30000 / (cfg["users"] + cfg["venues"])),
+                      2**31 + 3)
+    n = g.n_nodes + 64
+    g = lbsn.Graph(n, g.edges, np.concatenate([g.coords,
+                                               np.zeros((64, 2), np.float32)]),
+                   np.concatenate([g.spatial_mask, np.zeros(64, bool)]))
+    index = build_index(make_graph(n, g.edges, g.coords, g.spatial_mask),
+                        "2dreach-pointer")
+    return g, index
+
+
+def mixed_tiles(g, index, comp, n_tiles, rng):
+    """``n_tiles`` query tiles of ``TB`` lanes, each mixing two users of
+    the giant SCC, two users with no friend, two venues and two vertices
+    with no tree, under rects of 1-20% of the extent, some on a venue
+    and some inverted."""
+    from bench import lbsn, traffic
+
+    users = ~g.spatial_mask
+    tree = index.lookup_tree(np.arange(g.n_nodes))
+    friend = np.zeros(g.n_nodes, bool)
+    friend[g.edges[users[g.edges[:, 1]], 0]] = True
+    giant = np.argmax(np.bincount(comp[users]))
+    kinds = [np.nonzero(users & (comp == giant))[0],
+             np.nonzero(users & ~friend & (tree >= 0))[0],
+             np.nonzero(g.spatial_mask)[0],
+             np.nonzero(users & (tree < 0))[0]]
+    assert all(len(k) for k in kinds)
+    us = np.stack([rng.choice(k, (n_tiles, 2)) for k in kinds], axis=2)
+    us = rng.permuted(us.reshape(n_tiles, TB), axis=1).reshape(-1)
+    ext = lbsn.spatial_extent(g)
+    ratios = rng.choice([0.01, 0.02, 0.05, 0.10, 0.20], len(us))
+    rects = np.concatenate([traffic.region_for_extent(ext, r, 1, rng)
+                            for r in ratios])
+    on = rng.choice(kinds[2], 16)
+    rects[:16] = np.concatenate([g.coords[on], g.coords[on]], axis=1)
+    rects[16:32] = rects[16:32][:, [2, 1, 0, 3]]     # xmin > xmax
+    rects[32:48] = rects[32:48][:, [0, 3, 2, 1]]     # ymin > ymax
+    return us, rects
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_pointer_many_components_match_oracle_and_reference(yelp_pointer,
+                                                           impl):
+    """The pointer path on the Yelp shape, through the fused XLA program
+    and the megakernel (interpret mode on the CPU), against the core
+    oracle and the benchmark's own reference, with the candidate counts
+    of the two implementations equal."""
+    from bench import reference
+    from repro.core.oracle import rangereach_oracle_batch
+
+    g, index = yelp_pointer
+    ref = reference.Reference(g.n_nodes, g.edges, g.coords, g.spatial_mask)
+    us, rects = mixed_tiles(g, index, ref.comp, 32,
+                            np.random.default_rng(5))
+    want = rangereach_oracle_batch(
+        make_graph(g.n_nodes, g.edges, g.coords, g.spatial_mask), us, rects)
+    np.testing.assert_array_equal(ref.answers(us, rects), want)
+    assert 0 < want.sum() < len(want)
+    engines = {}
+    for name in {"xla", impl}:
+        eng = engines[name] = ShardedEngine(index, n_shards=1)
+        eng._fused_impl = name
+        np.testing.assert_array_equal(eng.query_batch(us, rects), want)
+    assert engines[impl].n_tiles > 1024
+    assert engines[impl].stats["tiles_scanned"] == \
+        engines["xla"].stats["tiles_scanned"]
+    assert engines[impl].stats["trees_routed"] > 32
+
+
+def test_trees_routed_counts_distinct_trees_per_batch(yelp_pointer):
+    """``stats["trees_routed"]`` sums, over batches, the distinct trees the
+    real lanes of each batch route to; venues and vertices with no tree
+    route to none."""
+    from bench import reference
+
+    g, index = yelp_pointer
+    comp = reference.Reference(g.n_nodes, g.edges, g.coords,
+                               g.spatial_mask).comp
+    eng = ShardedEngine(index, n_shards=1)
+    rng = np.random.default_rng(6)
+    want = 0
+    for b in range(3):
+        us, rects = mixed_tiles(g, index, comp, 4 + b, rng)
+        eng.query_batch(us, rects)
+        t = index.lookup_tree(us)
+        want += len(set(t[(t >= 0) & ~index.excluded[us]].tolist()))
+    assert eng.stats["batches"] == 3
+    assert eng.stats["trees_routed"] == want

@@ -108,6 +108,46 @@ def test_fused_megakernel_matches_xla_impl(indexes, graph):
     assert (cx.ids == cp.ids).all() and (cx.counts == cp.counts).all()
 
 
+@pytest.mark.parametrize("mode,kcap", [("reach", 64), ("count", 64),
+                                       ("collect", 64), ("reach", 8)])
+def test_megakernel_matches_xla_on_a_wide_arena(mode, kcap):
+    """An arena of 2,348 leaf tiles: the megakernel (interpret) and the
+    fused XLA program agree bit for bit on the answers, the candidate
+    counts and, in collect mode, the worklist order, with query slices
+    that span the arena, cross a thousand-tile boundary, end in the
+    last tile or are empty, and a capacity below some counts (the
+    truncated-scan contract)."""
+    rng = np.random.default_rng(7)
+    nt = 2348
+    P, c = nt * TP, 1024 * TP
+    pts = rng.uniform(0, 100, (P, 2)).astype(np.float32)
+    pts = pts[np.argsort(pts[:, 0], kind="stable")]   # tiles in x strips
+    esoa = np.concatenate([pts.T, pts.T])
+    fine, coarse, n = build_tile_pyramid(esoa, dim=2)
+    assert n == nt
+    grid = F.make_quant_grid(np.array([0, 0, 100, 100], np.float64), 2)
+    B = 2 * TB
+    slices = np.array([[0, P], [c - 5 * TP, c + 9 * TP + 3],
+                       [2 * c + 7, P - 11], [0, 0], [c, 2 * c],
+                       [5, c - 1], [P - TP, P], [2 * c - TP, 2 * c + TP]]
+                      * (B // 8), np.int32)
+    lo = rng.uniform(0, 95, (B, 2))
+    rsoa = np.concatenate([lo, lo + rng.uniform(0.2, 5, (B, 2))],
+                          axis=1).T.astype(np.float32)
+    r16, r32 = F.quantize_rects(grid, jnp.asarray(rsoa), 2)
+    args = (F.quantize_fine(grid, jnp.asarray(fine), 2),
+            F.quantize_coarse(grid, jnp.asarray(coarse), 2),
+            jnp.asarray(esoa), jnp.arange(P, dtype=jnp.int32)[None],
+            r16, r32, jnp.asarray(rsoa), jnp.asarray(slices[:, 0]),
+            jnp.asarray(slices[:, 1]))
+    ox, cx = F.fused_serve_xla(*args, mode=mode, kcap=kcap, nt=nt)
+    op, cp = F.fused_serve_pallas(*args, mode=mode, kcap=kcap, nt=nt,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(cp), np.asarray(cx))
+    np.testing.assert_array_equal(np.asarray(op), np.asarray(ox))
+    assert (np.asarray(cx) > kcap).any() and np.asarray(ox).any()
+
+
 # ------------------------------------------------------------ quantization
 def _edge_arena(rng, P):
     """Entry arena whose venue boxes end exactly on tile-MBR edges."""
