@@ -30,11 +30,15 @@ module makes the device path agree:
   dense prune as much as one plane; the scoped-VMEM limit is reckoned
   from them (:func:`megakernel_vmem_bytes`).  In-kernel it (1)
   evaluates the hierarchical coarse∧fine prune, (2) counts the
-  surviving leaf tiles, and (3) walks them in ascending order — slot
-  k+1's tile is the smallest active lane above slot k's (a masked lane
-  min, no host compaction, no materialized candidate matrix) — with
-  double-buffered DMA: the next tile's HBM→VMEM copy is in flight while
-  the current tile's exact f32 predicate evaluates.  A ``mode`` flag
+  surviving leaf tiles and builds a next-active row once — the suffix
+  minimum of the active lane ids, held as (NTp / 128, 128) and made by
+  log-step rolls within each 128-lane chunk and a carry from chunk to
+  chunk — and (3) walks the actives in ascending order, slot k+1's
+  tile read from that row at slot k's tile + 1 (one 128-lane row and
+  one lane reduction whatever the arena's width; no host compaction, no
+  materialized candidate matrix), with double-buffered DMA: the next
+  tile's HBM→VMEM copy is in flight while the current tile's exact f32
+  predicate evaluates.  A ``mode`` flag
   selects the epilogue — boolean OR, exact count, or collect
   (ids-or-sentinel written per worklist slot) — so one kernel serves
   ``query/count/collect_batch``.
@@ -69,7 +73,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .analytics import ID_SENTINEL
-from .descent import COARSE_GROUP
+from .descent import COARSE_GROUP, TPT
 from .kernel import TB, TP, box_hits, query_rows, query_specs
 
 # int16 fine-plane code space: finite bounds clip to [I16_LO, I16_HI];
@@ -234,14 +238,14 @@ def compact_ascending(mask: jax.Array, nt: int
 # The megakernel (one pallas_call: prune + compact + double-buffered scan)
 # --------------------------------------------------------------------------
 
-# (TB, NTp) int32 values (a (1, NTp) row pads to as many sublanes) that
-# the dense prune and the walk hold in VMEM at once beside the pyramid:
-# the widened fine plane, the coarse plane, the lane ids, the slice test,
-# the fine and coarse box tests and their partial results, the reduced
-# row and the walk's masked row.  Compiled for a v5e, the kernel asks
-# for 319-327 bytes a lane in all at 21K-169K lanes, under the 352 that
-# this reckons (64 for the planes, 32 for each value)
-_LIVE_ROWS = 9
+# (NTp / TPT, TPT) int32 values, 4 bytes a lane, that the walk's build
+# holds in scoped VMEM at once: the next-active row, the row being folded
+# and its rolled copy, the carry column (a (NTp / TPT, 1) value pads to
+# TPT lanes) and its rolled copy, and the select between them.  The prune
+# is not among them: compiled for a v5e it keeps no (TB, NTp) value, and
+# the kernel asks 15-25 bytes of scoped VMEM a lane at 21K-84K lanes in
+# reach mode (21-40 in collect, with its 512 KiB output block)
+_LIVE_ROWS = 6
 # the compiler's own scoped-VMEM limit on a v5e: wherever the reckoning
 # fits it no limit is passed, so the kernel compiles as it always has
 _SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
@@ -258,12 +262,12 @@ def megakernel_vmem_bytes(ntp: int, *, dim: int = 2, tb: int = TB,
                           kcap: int = 1) -> int:
     """VMEM one grid step of the megakernel holds over a pyramid of
     ``ntp`` lanes, reckoned as the chip tiles it: the two pyramid planes
-    (one buffer each), the prune's and the walk's ``_LIVE_ROWS`` values,
-    and the double-buffered per-query-tile blocks and leaf-tile DMA
-    buffers.  It grows with the arena only through the (·, NTp) terms."""
+    (one buffer each), the walk's ``_LIVE_ROWS`` values, and the
+    double-buffered per-query-tile blocks and leaf-tile DMA buffers.  It
+    grows with the arena only through the (·, NTp) terms."""
     two = 2 * dim
     planes = _padded_bytes(two, ntp, 2) + _padded_bytes(two, ntp, 4)
-    live = _LIVE_ROWS * _padded_bytes(tb, ntp, 4)
+    live = _LIVE_ROWS * _padded_bytes(ntp // TPT, TPT, 4)
     collect = mode == "collect"
     out_lanes = kcap * tp if collect else 1
     blocks = 2 * (_padded_bytes(tb, 2 * two, 4) + _padded_bytes(tb, two, 4)
@@ -289,12 +293,40 @@ def vmem_limit(need: int) -> int | None:
     return need if need > _SCOPED_VMEM_DEFAULT else None
 
 
+def _next_active(v):
+    """Suffix minimum of a (1, NTp) row of active lane ids (``ntp`` on
+    an inactive lane), folded into (NTp / TPT, TPT), one vreg row a
+    chunk: entry (c, l) is the smallest active lane >= c * TPT + l, or
+    ``ntp``.  A
+    log-step roll within each chunk, then a log-step carry of each
+    chunk's first active down the column of chunks."""
+    ntp = v.shape[1]
+    rows = ntp // TPT
+    v = v.reshape(rows, TPT)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, TPT), 1)
+    s = 1
+    while s < TPT:              # v[l] = min(v[l], v[l + s]) in a chunk
+        v = jnp.minimum(v, jnp.where(
+            lane < TPT - s, pltpu.roll(v, TPT - s, 1), ntp))
+        s *= 2
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    # the first active of the next chunk on, then of every later one
+    later = jnp.where(row < rows - 1, pltpu.roll(v[:, :1], rows - 1, 0),
+                      ntp)
+    s = 1
+    while s < rows:
+        later = jnp.minimum(later, jnp.where(
+            row < rows - s, pltpu.roll(later, rows - s, 0), ntp))
+        s *= 2
+    return jnp.minimum(v, later)
+
+
 def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
                   mode: str, kcap: int, dim: int, tp: int):
     if mode == "collect":
-        ids_any, o_ref, cnt_ref, ebuf, esem, ibuf, isem = rest
+        ids_any, o_ref, cnt_ref, nxt_ref, ebuf, esem, ibuf, isem = rest
     else:
-        o_ref, cnt_ref, ebuf, esem = rest
+        o_ref, cnt_ref, nxt_ref, ebuf, esem = rest
         ids_any = ibuf = isem = None
 
     qs = qse_ref[:, 0:1]                    # (TB, 1)
@@ -312,14 +344,20 @@ def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
     act = jnp.max(ok.astype(jnp.int32), axis=0, keepdims=True) > 0
 
     # ---- phase 2: worklist size; slots are the actives in ascending
-    # order, each found from the previous one by a masked lane min ------
+    # order, each read from the next-active row built once here --------
     cnt = jnp.sum(act.astype(jnp.int32))
     cnt_ref[...] = jnp.full(cnt_ref.shape, cnt, jnp.int32)
     n = jnp.minimum(cnt, kcap)
+    nxt_ref[...] = _next_active(jnp.where(act, lanes, ntp))
+    chunk_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, TPT), 1)
 
-    def next_tile(t):
-        """Smallest active tile id > t (``ntp`` when none is left)."""
-        return jnp.min(jnp.where(act & (lanes > t), lanes, ntp))
+    def tile_at(i):
+        """Smallest active tile id >= i (``ntp`` when none is left):
+        one ``TPT``-lane row of ``nxt_ref`` and one lane reduction."""
+        j = jnp.minimum(i, ntp - 1)
+        row = nxt_ref[pl.ds(j // TPT, 1), :]
+        t = jnp.min(jnp.where(chunk_lanes == j % TPT, row, ntp))
+        return jnp.where(i < ntp, t, ntp)
 
     # ---- phase 3: double-buffered masked scan over the worklist -------
     q = q_ref[...]                          # (TB, 2*dim) exact f32 rects
@@ -336,7 +374,7 @@ def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
                 isem.at[slot]))
         return cps
 
-    t0 = next_tile(-1)
+    t0 = tile_at(0)
 
     @pl.when(n > 0)
     def _first():
@@ -349,7 +387,7 @@ def _fused_kernel(qf_ref, qc_ref, rq_ref, q_ref, qse_ref, e_any, *rest,
     def body(k, carry):
         t, acc = carry
         slot = jax.lax.rem(k, 2)
-        t_next = next_tile(t)
+        t_next = tile_at(t + 1)
 
         @pl.when(k + 1 < n)
         def _next():
@@ -419,7 +457,8 @@ def fused_serve_pallas(
     ntp = qfine.shape[1]
     assert two_dim == 2 * dim
     assert P % tp == 0 and B % tb == 0, (P, B)
-    assert ntp % group == 0 and qcoarse.shape == (two_dim, ntp // group)
+    assert ntp % TPT == 0 and ntp % group == 0
+    assert qcoarse.shape == (two_dim, ntp // group)
     assert mode in ("reach", "count", "collect"), mode
     nb = B // tb
     kcap = max(int(kcap), 1)
@@ -435,6 +474,7 @@ def fused_serve_pallas(
     args = [qfine, jnp.repeat(qcoarse, group, axis=1), rq,
             *query_rows(rects_soa, qstart, qend), entries_soa]
     scratch = [
+        pltpu.VMEM((ntp // TPT, TPT), jnp.int32),      # next-active row
         pltpu.VMEM((2, two_dim, tp), jnp.float32),      # tile buffers
         pltpu.SemaphoreType.DMA((2,)),
     ]

@@ -17,6 +17,7 @@ from repro.core import QueryEngine, build_2dreach
 from repro.core.graph import make_graph
 from repro.data import get_dataset, workload
 from repro.kernels.range_query import fused as F
+from repro.kernels.range_query.analytics import ID_SENTINEL
 from repro.kernels.range_query.descent import (
     build_tile_pyramid,
     prune_tiles_ref,
@@ -108,24 +109,32 @@ def test_fused_megakernel_matches_xla_impl(indexes, graph):
     assert (cx.ids == cp.ids).all() and (cx.counts == cp.counts).all()
 
 
-@pytest.mark.parametrize("mode,kcap", [("reach", 64), ("count", 64),
-                                       ("collect", 64), ("reach", 8)])
-def test_megakernel_matches_xla_on_a_wide_arena(mode, kcap):
-    """An arena of 2,348 leaf tiles: the megakernel (interpret) and the
-    fused XLA program agree bit for bit on the answers, the candidate
-    counts and, in collect mode, the worklist order, with query slices
-    that span the arena, cross a thousand-tile boundary, end in the
-    last tile or are empty, and a capacity below some counts (the
-    truncated-scan contract)."""
+# Leaf-tile runs [a, b] that each query of a query tile activates, for an
+# arena of exactly 18 chunks of 128 tiles (so lane ntp - 1 is a real
+# tile); a query tile lists up to TB runs, the rest of its queries miss
+_LOOKUP_NT = 18 * 128
+_LOOKUP_CASES = {
+    "ends": [[(0, 0), (_LOOKUP_NT - 1, _LOOKUP_NT - 1)], [(1, 1)]],
+    "chunk-crossing": [[(120, 135), (250, 260), (1020, 1030)],
+                       [(127, 128), (1151, 1153)]],
+    "full-chunk": [[(384, 511)], [(383, 512)]],
+    "far-apart": [[(5, 5), (2200, 2200)], [(130, 130), (1700, 1700)]],
+    "none": [[], []],
+    "kcap-below-count": [[(0, 40), (700, 740)], [(2000, 2100)]],
+}
+_LOOKUP_KCAP = {"kcap-below-count": 64}
+
+
+def _wide_arena_slices():
+    """An arena of 2,348 leaf tiles, random rects, and query slices that
+    span the arena, cross a thousand-tile boundary, end in the last tile
+    or are empty."""
     rng = np.random.default_rng(7)
     nt = 2348
     P, c = nt * TP, 1024 * TP
     pts = rng.uniform(0, 100, (P, 2)).astype(np.float32)
     pts = pts[np.argsort(pts[:, 0], kind="stable")]   # tiles in x strips
     esoa = np.concatenate([pts.T, pts.T])
-    fine, coarse, n = build_tile_pyramid(esoa, dim=2)
-    assert n == nt
-    grid = F.make_quant_grid(np.array([0, 0, 100, 100], np.float64), 2)
     B = 2 * TB
     slices = np.array([[0, P], [c - 5 * TP, c + 9 * TP + 3],
                        [2 * c + 7, P - 11], [0, 0], [c, 2 * c],
@@ -134,10 +143,62 @@ def test_megakernel_matches_xla_on_a_wide_arena(mode, kcap):
     lo = rng.uniform(0, 95, (B, 2))
     rsoa = np.concatenate([lo, lo + rng.uniform(0.2, 5, (B, 2))],
                           axis=1).T.astype(np.float32)
+    return esoa, np.array([0, 0, 100, 100], np.float64), rsoa, slices, None
+
+
+def _wide_arena_runs(case):
+    """Tile t holds points with x in [t + 0.2, t + 0.8], so a rect over
+    x in [a + 0.1, b + 0.9] meets points of tiles a..b and activates no
+    other, by more than the quantization's slack; a query with no run
+    looks left of the arena."""
+    rng = np.random.default_rng(11)
+    nt = _LOOKUP_NT
+    P = nt * TP
+    x = (np.arange(P) // TP + rng.uniform(0.2, 0.8, P)).astype(np.float32)
+    y = rng.uniform(0, 100, P).astype(np.float32)
+    esoa = np.stack([x, y, x, y])
+    tiles = _LOOKUP_CASES[case]
+    rsoa = np.tile(np.array([[-50.0], [0.0], [-40.0], [100.0]], np.float32),
+                   (1, TB * len(tiles)))
+    want = []
+    for i, runs in enumerate(tiles):
+        for j, (a, b) in enumerate(runs):
+            rsoa[[0, 2], i * TB + j] = a + 0.1, b + 0.9
+        want.append(len(set().union(*(range(a, b + 1) for a, b in runs))))
+    slices = np.tile([0, P], (rsoa.shape[1], 1)).astype(np.int32)
+    return (esoa, np.array([0, 0, nt, 100], np.float64), rsoa, slices,
+            np.array(want, np.int32))
+
+
+_WIDE_CASES = (
+    [pytest.param(m, k, None, id=f"{m}-{k}") for m, k in
+     [("reach", 64), ("count", 64), ("collect", 64), ("reach", 8)]]
+    + [pytest.param(m, _LOOKUP_KCAP.get(case, 256), case,
+                    id=f"{case}-{m}")
+       for case in _LOOKUP_CASES for m in ("reach", "count", "collect")])
+
+
+@pytest.mark.parametrize("mode,kcap,case", _WIDE_CASES)
+def test_megakernel_matches_xla_on_a_wide_arena(mode, kcap, case):
+    """The megakernel (interpret) and the fused XLA program agree bit for
+    bit on the answers, the candidate counts and, in collect mode, the
+    worklist order.  On 2,348 leaf tiles with random rects and query
+    slices, and a capacity below some counts (the truncated-scan
+    contract); and on 18 chunks of 128 tiles with the active tiles
+    placed on the edges of the walk's next-active lookup: the first and
+    the last lane, runs across a chunk boundary, one full chunk, actives
+    many chunks apart, none at all, and more actives than the capacity."""
+    esoa, extent, rsoa, slices, want = (
+        _wide_arena_slices() if case is None
+        else _wide_arena_runs(case))
+    fine, coarse, nt = build_tile_pyramid(esoa, dim=2)
+    assert nt == esoa.shape[1] // TP
+    grid = F.make_quant_grid(extent, 2)
     r16, r32 = F.quantize_rects(grid, jnp.asarray(rsoa), 2)
     args = (F.quantize_fine(grid, jnp.asarray(fine), 2),
             F.quantize_coarse(grid, jnp.asarray(coarse), 2),
-            jnp.asarray(esoa), jnp.arange(P, dtype=jnp.int32)[None],
+            jnp.asarray(esoa),
+            jnp.arange(esoa.shape[1], dtype=jnp.int32)[None],
             r16, r32, jnp.asarray(rsoa), jnp.asarray(slices[:, 0]),
             jnp.asarray(slices[:, 1]))
     ox, cx = F.fused_serve_xla(*args, mode=mode, kcap=kcap, nt=nt)
@@ -145,7 +206,13 @@ def test_megakernel_matches_xla_on_a_wide_arena(mode, kcap):
                                   interpret=True)
     np.testing.assert_array_equal(np.asarray(cp), np.asarray(cx))
     np.testing.assert_array_equal(np.asarray(op), np.asarray(ox))
-    assert (np.asarray(cx) > kcap).any() and np.asarray(ox).any()
+    if case is None:
+        assert (np.asarray(cx) > kcap).any() and np.asarray(ox).any()
+    else:
+        np.testing.assert_array_equal(np.asarray(cx), want)
+        assert (want > kcap).any() == (case == "kcap-below-count")
+        empty = 0 if mode != "collect" else ID_SENTINEL
+        assert (np.asarray(ox) != empty).any() == (case != "none")
 
 
 # ------------------------------------------------------------ quantization
