@@ -1,1 +1,1 @@
-"""Benchmark harness: paper tables/figures + roofline readers."""
+"""Benchmark harness: paper tables/figures and serving benches."""

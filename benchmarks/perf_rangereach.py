@@ -1,8 +1,8 @@
 """§Perf hillclimb: the paper-technique cell (RangeReach query engine).
 
-Unlike the LM/GNN cells (dry-run roofline terms), the paper's own
-workload runs for real on this host, so this hillclimb measures
-wall-clock per query across engine variants and structural parameters:
+The paper's own workload runs for real on this host, so this hillclimb
+measures wall-clock per query across engine variants and structural
+parameters:
 
     engine    host wavefront | jit wavefront (capacity c) | pallas leaf
               | device (compile-once QueryEngine, hierarchical descent)

@@ -1,4 +1,4 @@
-"""Benchmark harness — one section per paper table/figure + roofline.
+"""Benchmark harness — one section per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--full] [--scale 0.5]
                                             [--queries 400]
@@ -10,7 +10,6 @@ Sections:
     [fig3]   query-time sweeps (3 parameters x 6 methods x 4 datasets)
              + the stability ratio behind the paper's headline claim
     [kernels] Pallas kernel microbenches (interpret mode on CPU)
-    [roofline] dry-run derived terms, if results/dryrun exists
 """
 
 from __future__ import annotations
@@ -91,19 +90,6 @@ def main() -> None:
 
     _section("kernel microbenches (interpret mode — correctness-scale)")
     _kernel_bench()
-
-    _section("roofline (from results/dryrun, single-pod mesh)")
-    try:
-        from . import roofline
-
-        rows = roofline.table()
-        if rows:
-            print(roofline.format_table(rows))
-        else:
-            print("no dry-run results yet "
-                  "(run: python -m repro.launch.dryrun --all)")
-    except Exception as e:
-        print("roofline unavailable:", e)
 
     print(f"\n[benchmarks] total {time.perf_counter() - t_start:.1f}s")
 

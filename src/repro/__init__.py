@@ -1,2 +1,2 @@
-"""repro: 2DReach geosocial reachability as a multi-pod JAX framework."""
+"""repro: 2DReach geosocial reachability queries served from JAX and Pallas."""
 __version__ = "0.1.0"

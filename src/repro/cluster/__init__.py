@@ -19,7 +19,7 @@ from .partition import (
     partition_forest,
     shard_arenas,
 )
-from .sharded_engine import ShardedEngine, sharded_engine_for
+from .sharded_engine import ShardedEngine, make_shard_mesh, sharded_engine_for
 
 __all__ = [
     "Frontend",
@@ -28,5 +28,6 @@ __all__ = [
     "partition_forest",
     "shard_arenas",
     "ShardedEngine",
+    "make_shard_mesh",
     "sharded_engine_for",
 ]

@@ -65,7 +65,6 @@ from ..core.engine import (
     pad_batch,
 )
 from ..core.two_d_reach import TwoDReachIndex
-from ..distributed.sharding import index_shard_specs
 from ..kernels.range_query.descent import (
     descent_scan_pallas,
     prune_tiles_pallas,
@@ -79,12 +78,22 @@ from ..kernels.range_query.fused import (
     quantize_rects,
 )
 from ..kernels.range_query.kernel import TB
-from ..launch.mesh import make_shard_mesh
 from ..obs import span
 from ..resilience.faults import fault_point
 from .partition import partition_forest, shard_arenas
 
 _AXIS = "data"
+
+
+def make_shard_mesh(n_dev: Optional[int] = None):
+    """1-D query-serving mesh over the ``data`` axis.
+
+    :class:`ShardedEngine` shards the 2DReach forest over this axis
+    (``launch/serve.py --engine cluster``).  ``n_dev`` defaults to every
+    local device.
+    """
+    n = len(jax.devices()) if n_dev is None else int(n_dev)
+    return jax.make_mesh((n,), (_AXIS,))
 
 
 def _devices_for(n_shards: int, n_avail: int) -> int:
@@ -224,14 +233,17 @@ class ShardedEngine:
         self.partition = partition_forest(index.forest, n_shards)
         entries, fine, coarse, nt = shard_arenas(index.forest, self.partition)
         self.n_tiles = nt                       # per shard, uniform
-        specs = index_shard_specs(_AXIS)
+        # the stacked per-shard arenas, shaped (S, 2*dim, width), shard
+        # over the data axis on the leading dim; the vertex->tree routing
+        # arrays are replicated (every device routes every query)
+        arena = P(_AXIS, None, None)
 
         def put(x, spec):
             return jax.device_put(x, NamedSharding(mesh, spec))
 
-        self._entries = put(entries, specs["entries"])
-        self._fine = put(fine, specs["fine"])
-        self._coarse = put(coarse, specs["coarse"])
+        self._entries = put(entries, arena)
+        self._fine = put(fine, arena)
+        self._coarse = put(coarse, arena)
         # quantized MBR planes for the fused collective program: one
         # grid over the whole forest extent (soundness only needs the
         # rounding to be outward; sharing the grid keeps the replicated
@@ -245,14 +257,14 @@ class ShardedEngine:
             self.dim), replicated)
         self._qfine = put(
             jax.vmap(lambda p: quantize_fine(self._grid, p, self.dim))(
-                jnp.asarray(fine)), specs["fine"])
+                jnp.asarray(fine)), arena)
         self._qcoarse = put(
             jax.vmap(lambda p: quantize_coarse(self._grid, p, self.dim))(
-                jnp.asarray(coarse)), specs["coarse"])
+                jnp.asarray(coarse)), arena)
         # replicated routing: tree -> (owning shard, local arena slice),
         # passed to the shard_map programs as arguments
         self._routing = tuple(
-            put(np.asarray(getattr(self.partition, k)), specs[k])
+            put(np.asarray(getattr(self.partition, k)), P())
             for k in ("tree_shard", "tree_qs", "tree_qe"))
         self._side = PointerSide(index, sharding=replicated)
 

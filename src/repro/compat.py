@@ -1,6 +1,6 @@
 """``jax.shard_map`` with the replication check off by default.
 
-The serving and model programs return values they reduce themselves
+The serving programs return values they reduce themselves
 (``psum``/``pmax``), so the static varying-manual-axes check
 (``check_vma``) is opt-in.
 """

@@ -1,8 +1,7 @@
-"""Data substrate: synthetic LBSN graphs, query workloads, training
-pipelines, and the GraphSAGE-style neighbour sampler."""
+"""Data substrate: synthetic LBSN graphs, the dataset registry and query
+workloads."""
 
 from .lbsn import SPECS, LBSNSpec, dataset_stats, generate_lbsn
-from .pipeline import ShardInfo, din_batches, lm_batches, molecule_batches
 from .queries import (
     DEGREE_BUCKETS,
     DEGREE_DEFAULT,
@@ -22,4 +21,3 @@ from .queries import (
     zipf_workload,
 )
 from .registry import dataset_names, get_dataset
-from .sampler import SampledBlock, pad_block, sample_blocks

@@ -6,10 +6,8 @@ bitset_mm/    — packed uint32 boolean OR-AND matmul (the Alg. 1 closure
                 build step as a semiring matmul; + MXU variant in ops).
 forest_build/ — segmented-MBR reduction (the R-tree bulk-load level
                 step; also builds the query engines' tile pyramids).
-segment_bag/  — fused EmbeddingBag gather+segment-sum (recsys/GNN
-                substrate; JAX has no native EmbeddingBag).
 
 Each: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd public
 wrapper), ref.py (pure-jnp oracle). Validated vs ref in interpret mode;
-see tests/test_kernels_*.py for the shape/dtype sweeps.
+see tests/test_kernels.py for the shape sweeps.
 """

@@ -256,7 +256,7 @@ def test_sharded_engine_for_memoised_and_strict(graph, indexes):
 def test_sharded_mesh_divisibility(indexes):
     import jax
 
-    from repro.launch.mesh import make_shard_mesh
+    from repro.cluster import make_shard_mesh
 
     if len(jax.devices()) >= 2:     # exercised by the CI 8-device job
         mesh = make_shard_mesh(2)

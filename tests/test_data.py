@@ -1,17 +1,8 @@
-"""Data substrate: LBSN shaping, workloads, pipelines determinism."""
+"""Data substrate: LBSN shaping and query workloads."""
 
 import numpy as np
 
-from repro.data import (
-    SPECS,
-    dataset_stats,
-    din_batches,
-    get_dataset,
-    lm_batches,
-    molecule_batches,
-    workload,
-)
-from repro.data.pipeline import ShardInfo
+from repro.data import dataset_stats, get_dataset, workload
 
 
 def test_lbsn_shapes_match_paper_structure():
@@ -44,33 +35,3 @@ def test_workload_parameters():
                   & (pts[:, 1] >= r[1]) & (pts[:, 1] <= r[3])).sum()
         assert inside >= k  # grown to cover at least k
 
-
-def test_pipelines_deterministic_and_sharded():
-    a = next(lm_batches(100, 16, 8, seed=3))
-    b = next(lm_batches(100, 16, 8, seed=3))
-    assert np.array_equal(a["tokens"], b["tokens"])
-    # different hosts see different slices; shapes divide
-    h0 = next(lm_batches(100, 16, 8, seed=3, shard=ShardInfo(0, 4)))
-    h1 = next(lm_batches(100, 16, 8, seed=3, shard=ShardInfo(1, 4)))
-    assert h0["tokens"].shape == (2, 16)
-    assert not np.array_equal(h0["tokens"], h1["tokens"])
-    # start_step resumes mid-stream identically
-    it = lm_batches(100, 16, 8, seed=3)
-    next(it)
-    second = next(it)
-    resumed = next(lm_batches(100, 16, 8, seed=3, start_step=1))
-    assert np.array_equal(second["tokens"], resumed["tokens"])
-
-
-def test_din_batches_have_signal():
-    b = next(din_batches(1000, 20, 16, 256, seed=0))
-    assert b["hist_items"].shape == (256, 16)
-    assert 0.05 < b["label"].mean() < 0.95
-
-
-def test_molecule_batches():
-    b = next(molecule_batches(12, 32, 8, seed=0))
-    assert b["pos"].shape == (8, 12, 3)
-    assert b["edge_src"].shape == (8, 32)
-    assert np.isfinite(b["energy"]).all()
-    assert b["edge_mask"].any(axis=1).all()

@@ -3,13 +3,10 @@
 import numpy as np
 import pytest
 
-import jax.numpy as jnp
-
 from repro.core import build_forest, query_host
 from repro.core.reachability import pack_rows, unpack_rows
 from repro.kernels.bitset_mm.ops import bitset_mm, bitset_mm_mxu
 from repro.kernels.range_query.ops import range_query_forest
-from repro.kernels.segment_bag.ops import embedding_bag
 
 
 # ---------------------------------------------------------------- range_query
@@ -53,41 +50,3 @@ def test_bitset_mm_sweep(d, dj, p):
     got_mxu = bitset_mm_mxu(a_bits, rpad)[:d]
     assert np.array_equal(got_mxu, want)
 
-
-# ---------------------------------------------------------------- segment_bag
-@pytest.mark.parametrize("V,D,B,maxlen", [(10, 8, 1, 3), (100, 32, 17, 7),
-                                          (64, 128, 9, 0), (257, 16, 40, 12)])
-@pytest.mark.parametrize("mode", ["sum", "mean"])
-def test_segment_bag_sweep(V, D, B, maxlen, mode):
-    rng = np.random.default_rng(V + D * 3 + B * 7 + maxlen)
-    table = rng.standard_normal((V, D)).astype(np.float32)
-    lens = rng.integers(0, maxlen + 1, size=B)
-    offsets = np.zeros(B + 1, dtype=np.int64)
-    np.cumsum(lens, out=offsets[1:])
-    idx = rng.integers(0, V, size=int(lens.sum()))
-    want = np.zeros((B, D), np.float32)
-    for b in range(B):
-        rows = table[idx[offsets[b]:offsets[b + 1]]]
-        if len(rows):
-            want[b] = rows.sum(0) / (len(rows) if mode == "mean" else 1.0)
-    got = np.asarray(embedding_bag(table, idx, offsets, mode=mode,
-                                   interpret=True))
-    ref = np.asarray(embedding_bag(table, idx, offsets, mode=mode,
-                                   use_ref=True))
-    np.testing.assert_allclose(got, want, atol=1e-5)
-    np.testing.assert_allclose(ref, want, atol=1e-5)
-
-
-def test_segment_bag_dtype_bf16():
-    rng = np.random.default_rng(0)
-    table = rng.standard_normal((32, 16)).astype(np.float32)
-    offsets = np.array([0, 2, 5, 5, 8])
-    idx = rng.integers(0, 32, size=8)
-    got = embedding_bag(jnp.asarray(table, jnp.bfloat16), idx, offsets,
-                        interpret=True)
-    ref = embedding_bag(jnp.asarray(table, jnp.bfloat16), idx, offsets,
-                        use_ref=True)
-    # bf16 accumulation order differs between kernel and segment_sum ref
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(ref, np.float32),
-        atol=5e-2, rtol=2e-2)

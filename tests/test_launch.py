@@ -1,8 +1,6 @@
-"""Launcher-level coverage: the dry-run entry point end-to-end (512
-forced devices in a subprocess), serve CLI, and perf_lm override
-parsing."""
+"""Launcher-level coverage: the serve CLI end to end, in a
+subprocess."""
 
-import json
 import os
 import subprocess
 import sys
@@ -14,23 +12,6 @@ ENV = dict(os.environ, PYTHONPATH="src")
 
 
 @pytest.mark.slow
-def test_dryrun_single_cell_subprocess(tmp_path):
-    """Lower+compile one real cell on the 256-chip mesh, exactly as the
-    campaign does (subprocess so the 512-device XLA flag stays isolated)."""
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.launch.dryrun", "--arch", "schnet",
-         "--shape", "molecule"],
-        env=ENV, cwd=ROOT, capture_output=True, text=True, timeout=500)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "OK" in r.stdout
-    path = os.path.join(ROOT, "results", "dryrun",
-                        "schnet__molecule__pod16x16.json")
-    rec = json.load(open(path))
-    assert rec["ok"] and rec["n_devices"] == 256
-    assert rec["hlo_stats"]["flops"] > 0
-
-
-@pytest.mark.slow
 def test_serve_cli(tmp_path):
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--dataset", "tiny",
@@ -39,28 +20,3 @@ def test_serve_cli(tmp_path):
     assert r.returncode == 0, r.stdout + r.stderr
     assert "verified" in r.stdout
 
-
-def test_perf_lm_overrides():
-    from repro.launch.perf_lm import apply_overrides, parse_val
-    from repro.configs import get_arch
-
-    assert parse_val("true") is True
-    assert parse_val("2") == 2
-    assert parse_val("1.5") == 1.5
-    cfg = get_arch("deepseek-v3-671b").make_config()
-    out = apply_overrides(cfg, {
-        "attn_block_skip": True, "moe.balance_factor": 1.0})
-    assert out.attn_block_skip is True
-    assert out.moe.balance_factor == 1.0
-    assert out.moe.n_experts == cfg.moe.n_experts  # untouched fields kept
-
-
-def test_mesh_factories():
-    # importing mesh.py must not touch device state; factories produce
-    # the contracted shapes
-    from repro.launch import mesh as m
-
-    axes = m.mesh_axes(multi_pod=True)
-    assert axes.data == ("pod", "data")
-    axes1 = m.mesh_axes(multi_pod=False)
-    assert axes1.data == ("data",)
